@@ -363,7 +363,7 @@ def _bench_dma_overlap(flush=96, lane=16):
     s[:n_salt] = np.resize(heavy, n_salt)     # long rows -> deep worklists
     t[n_salt // 2:n_salt + n_salt // 2] = np.resize(heavy, n_salt)
     WLn = ragged_worklist_len(np.asarray(ar.tile_cnt), s, t)
-    qidx, stile, ttile, first = emit_ragged_worklist(
+    qidx, stile, ttile = emit_ragged_worklist(
         ar.tile_base, ar.tile_cnt, jnp.asarray(s), jnp.asarray(t),
         worklist_len=WLn)
     wq_lvl = jnp.concatenate([jnp.asarray(wl),
@@ -372,7 +372,7 @@ def _bench_dma_overlap(flush=96, lane=16):
     def run(nbuf):
         return np.asarray(wq.wcsd_query_ragged(
             ar.hub, ar.dist, ar.wlev, ar.tile_lo, ar.tile_hi,
-            qidx, stile, ttile, first, wq_lvl, nbuf=nbuf))
+            qidx, stile, ttile, wq_lvl, nbuf=nbuf))
 
     out4, out1 = run(4), run(1)               # warmup traces, both depths
     assert np.array_equal(out4, out1), \

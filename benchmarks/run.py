@@ -199,7 +199,9 @@ def _serving_in_subprocess(args) -> list:
     """Run the serving suite in a child process so its virtual-device
     topology (`xla_force_host_platform_device_count`) cannot leak into the
     other suites' measurements — jax locks the device count at first
-    initialization, so one process cannot serve both."""
+    initialization, so one process cannot serve both. The caller runs it
+    BEFORE its own process imports jax: a chip belongs to one process at a
+    time, so the child must hold it alone and exit first."""
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
         path = f.name
     cmd = [sys.executable, "-m", "benchmarks.run", "--only", "serving",
@@ -250,18 +252,24 @@ def main() -> None:
     # on a multi-device topology. When it is the ONLY suite, fix the
     # virtual device count in-process (appending — never clobbering — any
     # pre-existing XLA_FLAGS) BEFORE anything imports jax; when it runs
-    # alongside other suites it goes to a subprocess instead, so every
-    # other row keeps the default topology.
+    # alongside other suites it goes to a child process, run to completion
+    # before this process imports jax, so every other row keeps the
+    # default topology and only one process at a time holds the device.
     serving_in_proc = only == {"serving"}
+    serving_rows = None
     if serving_in_proc and args.host_devices > 1:
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.host_devices}").strip()
+    elif only is None or "serving" in only:
+        serving_rows = _serving_in_subprocess(args)
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     from benchmarks import bench_indexing, bench_kernels, bench_wcsd
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     suites = {
         "indexing": lambda: bench_wcsd.bench_indexing(
@@ -276,7 +284,7 @@ def main() -> None:
             configs=bench_indexing.QUICK_CONFIGS if args.quick else None),
         "serving": (lambda: bench_wcsd.bench_serving(
             batch=1024 if args.quick else 4096)) if serving_in_proc
-        else lambda: _serving_in_subprocess(args),
+        else lambda: serving_rows,
         "label_store": lambda: bench_wcsd.bench_label_store(
             dataset="MV(s)" if args.quick else "SO(s)",
             n_queries=256 if args.quick else 2048),

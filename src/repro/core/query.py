@@ -36,23 +36,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.wcsd_query import pad_group_rows
 from .graph import INF_DIST
 from .wc_index import (PackedLabels, PackedWCIndex, WCIndex, ceil_to,
                        round_to_lane, round_to_pow2)
 
 DEV_INF = jnp.int32(1 << 29)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` where it exists, `jax.experimental.shard_map` on
-    older jax — the serving engines replicate per-query integer math, so
-    replication checking is disabled on both spellings."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -205,11 +194,9 @@ def emit_ragged_worklist(tile_base, tile_cnt, s, t, *, worklist_len: int):
     the bucket-pair planner, so the host contributes only the O(B)
     worklist-capacity sum (`ragged_worklist_len`).
 
-    Returns (qidx, stile, ttile, first), all int32 [worklist_len]. Work
-    items beyond the real total carry ``qidx == len(s)`` — the caller's
-    kernel output owns one trash row at that index — and tile 0 on both
-    sides. ``first`` marks each output row's first work item (kernel-side
-    DEV_INF init), including the trash row's.
+    Returns (qidx, stile, ttile), all int32 [worklist_len]. Work items
+    beyond the real total carry ``qidx == len(s)`` — the caller's kernel
+    output owns one trash row at that index — and tile 0 on both sides.
     """
     Q = s.shape[0]
     ts = tile_cnt[s].astype(jnp.int32)
@@ -223,10 +210,7 @@ def emit_ragged_worklist(tile_base, tile_cnt, s, t, *, worklist_len: int):
     pad = qidx >= Q
     stile = jnp.where(pad, 0, tile_base[s[qc]] + local // tt[qc])
     ttile = jnp.where(pad, 0, tile_base[t[qc]] + local % tt[qc])
-    first = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32),
-         (qidx[1:] != qidx[:-1]).astype(jnp.int32)])
-    return qidx, stile, ttile, first
+    return qidx, stile, ttile
 
 
 def ragged_worklist_len(tile_cnt: np.ndarray, s: np.ndarray, t: np.ndarray
@@ -247,7 +231,8 @@ def ragged_query_batch(hub, dist, wlev, tile_lo, tile_hi,
                        compressed: bool = False):
     """Plan + launch, fused into ONE device call: emit the worklist from
     the staged queries and answer every query with a single ragged kernel
-    launch.
+    launch (several equal ones when the worklist outgrows one launch's
+    scalar memory — `kernels.wcsd_query.ragged_launches`).
 
     hub..tile_cnt: the `LabelArena` arrays; stq: [3, Q] staged
     (s, t, w_level) — one H2D transfer carries the whole batch. Returns
@@ -258,14 +243,14 @@ def ragged_query_batch(hub, dist, wlev, tile_lo, tile_hi,
     then be the compressed trio, the index arrays are shared."""
     from ..kernels import ops as kops
     s, t, wl = stq[0], stq[1], stq[2]
-    qidx, stile, ttile, first = emit_ragged_worklist(
+    qidx, stile, ttile = emit_ragged_worklist(
         tile_base, tile_cnt, s, t, worklist_len=worklist_len)
     # one trash output row for worklist pads; no stored wlev reaches 2^20,
     # so its level is infeasible at every entry
     wq = jnp.concatenate([wl, jnp.full((1,), 1 << 20, jnp.int32)])
     op = (kops.wcsd_query_ragged_compressed if compressed
           else kops.wcsd_query_ragged)
-    out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile, first,
+    out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile,
              wq, interpret=interpret, use_kernel=use_kernel)
     return out[: s.shape[0]]
 
@@ -282,11 +267,11 @@ def ragged_profile_batch(hub, dist, wlev, tile_lo, tile_hi,
     Returns [Q, num_levels + 1] staircases."""
     from ..kernels import ops as kops
     s, t = stq[0], stq[1]
-    qidx, stile, ttile, first = emit_ragged_worklist(
+    qidx, stile, ttile = emit_ragged_worklist(
         tile_base, tile_cnt, s, t, worklist_len=worklist_len)
     op = (kops.wcsd_profile_ragged_compressed if compressed
           else kops.wcsd_profile_ragged)
-    out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile, first,
+    out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile,
              num_rows=int(s.shape[0]) + 1, num_levels=num_levels,
              interpret=interpret, use_kernel=use_kernel)
     return out[: s.shape[0]]
@@ -518,7 +503,7 @@ class DeviceQueryEngine(_QueryEngineBase):
                     else:
                         self.compressed = True
                         src = comp
-                trio = ((src.hub_delta, src.dist, src.wlev)
+                trio = (pad_group_rows(src.hub_delta, src.dist, src.wlev)
                         if self.compressed else (src.hub, src.dist, src.wlev))
                 self._arena = tuple(jnp.asarray(a) for a in trio + (
                     src.tile_lo, src.tile_hi, src.tile_base, src.tile_cnt))
@@ -796,6 +781,8 @@ class ShardedQueryEngine(_QueryEngineBase):
                 if self.mode == "sharded_labels":
                     trio = self._shard_arena_tiles(trio)
                 else:
+                    if self.compressed:
+                        trio = pad_group_rows(*trio)
                     trio = tuple(jax.device_put(a, rep) for a in trio)
                 self._arena = trio + tuple(jax.device_put(a, rep1)
                                            for a in rest)
@@ -968,7 +955,8 @@ class ShardedQueryEngine(_QueryEngineBase):
 
             in_specs = (P(self.batch_axes, None),) * 3 \
                 + (P(self.batch_axes),) + (P(None),) * 3
-        fn = jax.jit(shard_map_compat(local, self.mesh, in_specs, q))
+        fn = jax.jit(jax.shard_map(local, mesh=self.mesh, in_specs=in_specs,
+                                  out_specs=q, check_vma=False))
         self._fns[key] = fn
         return fn
 
@@ -1156,7 +1144,7 @@ class ShardedQueryEngine(_QueryEngineBase):
                 def mine(a):
                     return jax.lax.dynamic_slice_in_dim(a, me * b, b)
 
-                qidx, stile, ttile, first = emit_ragged_worklist(
+                qidx, stile, ttile = emit_ragged_worklist(
                     tbase, tcnt, mine(stq[0]), mine(stq[1]),
                     worklist_len=WL)
                 # relabel worklist tiles into the gathered buffer: the
@@ -1168,7 +1156,7 @@ class ShardedQueryEngine(_QueryEngineBase):
                 sloc = jnp.searchsorted(uniq_me, stile).astype(jnp.int32)
                 tloc = jnp.searchsorted(uniq_me, ttile).astype(jnp.int32)
                 args = (gh, gd, gw, lo[uniq_me], hi[uniq_me], qidx,
-                        sloc, tloc, first)
+                        sloc, tloc)
                 if profile:
                     op = (kops.wcsd_profile_ragged_compressed if compressed
                           else kops.wcsd_profile_ragged)
@@ -1185,7 +1173,8 @@ class ShardedQueryEngine(_QueryEngineBase):
 
             in_specs = (P(self.batch_axes, None),) * 3 + (P(None),) * 4 \
                 + (P(None, None), P(None, None))
-        fn = jax.jit(shard_map_compat(local, self.mesh, in_specs, q))
+        fn = jax.jit(jax.shard_map(local, mesh=self.mesh, in_specs=in_specs,
+                                  out_specs=q, check_vma=False))
         self._fns[key] = fn
         return fn
 
@@ -1237,7 +1226,8 @@ class ShardedQueryEngine(_QueryEngineBase):
             tile = P(self.batch_axes, None)
             qspec = P(None, None)
         in_specs = (tile,) * 6 + (qspec,)
-        fn = jax.jit(shard_map_compat(local, self.mesh, in_specs, q))
+        fn = jax.jit(jax.shard_map(local, mesh=self.mesh, in_specs=in_specs,
+                                  out_specs=q, check_vma=False))
         self._fns[key] = fn
         return fn
 
@@ -1333,7 +1323,8 @@ class ShardedQueryEngine(_QueryEngineBase):
 
             in_specs = (P(self.batch_axes, None),) * 3 \
                 + (P(self.batch_axes),) + (P(None),) * 2
-        fn = jax.jit(shard_map_compat(local, self.mesh, in_specs, q))
+        fn = jax.jit(jax.shard_map(local, mesh=self.mesh, in_specs=in_specs,
+                                  out_specs=q, check_vma=False))
         self._fns[key] = fn
         return fn
 
@@ -1376,6 +1367,7 @@ class ShardedQueryEngine(_QueryEngineBase):
             tile = P(self.batch_axes, None)
             qspec = P(None, None)
         in_specs = (tile,) * 6 + (qspec,)
-        fn = jax.jit(shard_map_compat(local, self.mesh, in_specs, q))
+        fn = jax.jit(jax.shard_map(local, mesh=self.mesh, in_specs=in_specs,
+                                  out_specs=q, check_vma=False))
         self._fns[key] = fn
         return fn

@@ -309,7 +309,7 @@ def build_wc_index_batched_packed(
         g: Graph, order: Optional[np.ndarray] = None,
         ordering: str = "degree", batch_size: int = 32,
         minimalize: bool = True, use_kernel: bool = True,
-        interpret: bool = True) -> tuple[PackedWCIndex, dict]:
+        interpret: bool | None = None) -> tuple[PackedWCIndex, dict]:
     """Device-resident rank-batched construction emitting CSR directly.
 
     Same label semantics as `build_wc_index_batched` (identical entry
@@ -323,10 +323,14 @@ def build_wc_index_batched_packed(
     `PackedLabelsBuilder`, which finalizes straight into `PackedLabels` —
     no padded [V, cap] final labels, no serve-time repack.
 
+    ``interpret=None`` resolves through `kernels.ops.resolve_interpret`:
+    compiled kernels on TPU, interpret emulation elsewhere.
+
     Returns (PackedWCIndex, stats).
     """
     from ..kernels import ops as kops
 
+    interpret = kops.resolve_interpret(interpret)
     V, W = g.num_nodes, g.num_levels
     if order is None:
         order = make_order(g, ordering)

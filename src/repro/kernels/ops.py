@@ -120,15 +120,15 @@ def wcsd_query_segmented_staged(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
 def wcsd_query_ragged(hub, dist, wlev, tile_lo, tile_hi,
-                      qidx, stile, ttile, first, wq, *,
+                      qidx, stile, ttile, wq, *,
                       interpret: bool = True, use_kernel: bool = True):
     """One ragged sub-batch — which is the WHOLE batch: every bucket mix in
-    a single launch over the lane-tiled arena (see `kernels.wcsd_query.
-    wcsd_query_ragged` for the worklist contract). Returns [Q] int32
-    distances (INF_DIST when no feasible path)."""
+    one flush over the lane-tiled arena (see `kernels.wcsd_query.
+    wcsd_query_ragged` for the worklist contract and the SMEM split).
+    Returns [Q] int32 distances (INF_DIST when no feasible path)."""
     if use_kernel:
         best = _wq.wcsd_query_ragged(hub, dist, wlev, tile_lo, tile_hi,
-                                     qidx, stile, ttile, first, wq,
+                                     qidx, stile, ttile, wq,
                                      interpret=interpret)
     else:
         best = _ref.wcsd_query_ragged_ref(hub, dist, wlev, qidx, stile,
@@ -139,7 +139,7 @@ def wcsd_query_ragged(hub, dist, wlev, tile_lo, tile_hi,
 @functools.partial(jax.jit, static_argnames=("num_rows", "num_levels",
                                              "interpret", "use_kernel"))
 def wcsd_profile_ragged(hub, dist, wlev, tile_lo, tile_hi,
-                        qidx, stile, ttile, first, *, num_rows: int,
+                        qidx, stile, ttile, *, num_rows: int,
                         num_levels: int, interpret: bool = True,
                         use_kernel: bool = True):
     """Ragged PROFILE batch: same worklist contract as `wcsd_query_ragged`,
@@ -149,7 +149,7 @@ def wcsd_profile_ragged(hub, dist, wlev, tile_lo, tile_hi,
     [num_rows, num_levels + 1] int32 (INF_DIST where infeasible)."""
     if use_kernel:
         bucket = _wq.wcsd_profile_ragged(hub, dist, wlev, tile_lo, tile_hi,
-                                         qidx, stile, ttile, first,
+                                         qidx, stile, ttile,
                                          num_rows=num_rows,
                                          num_levels=num_levels,
                                          interpret=interpret)
@@ -162,7 +162,7 @@ def wcsd_profile_ragged(hub, dist, wlev, tile_lo, tile_hi,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
 def wcsd_query_ragged_compressed(hub_delta, dist, wlev, tile_lo, tile_hi,
-                                 qidx, stile, ttile, first, wq, *,
+                                 qidx, stile, ttile, wq, *,
                                  interpret: bool = True,
                                  use_kernel: bool = True):
     """`wcsd_query_ragged` over the COMPRESSED arena (CompressedArena
@@ -170,9 +170,9 @@ def wcsd_query_ragged_compressed(hub_delta, dist, wlev, tile_lo, tile_hi,
     output contract; callers must route overflowed stores to the
     uncompressed path."""
     if use_kernel:
-        best = _wq.wcsd_query_ragged_compressed(
+        best = _wq.wcsd_query_ragged(
             hub_delta, dist, wlev, tile_lo, tile_hi,
-            qidx, stile, ttile, first, wq, interpret=interpret)
+            qidx, stile, ttile, wq, interpret=interpret)
     else:
         best = _ref.wcsd_query_ragged_compressed_ref(
             hub_delta, dist, wlev, tile_lo, qidx, stile, ttile, wq)
@@ -182,15 +182,15 @@ def wcsd_query_ragged_compressed(hub_delta, dist, wlev, tile_lo, tile_hi,
 @functools.partial(jax.jit, static_argnames=("num_rows", "num_levels",
                                              "interpret", "use_kernel"))
 def wcsd_profile_ragged_compressed(hub_delta, dist, wlev, tile_lo, tile_hi,
-                                   qidx, stile, ttile, first, *,
+                                   qidx, stile, ttile, *,
                                    num_rows: int, num_levels: int,
                                    interpret: bool = True,
                                    use_kernel: bool = True):
     """`wcsd_profile_ragged` over the COMPRESSED arena."""
     if use_kernel:
-        bucket = _wq.wcsd_profile_ragged_compressed(
+        bucket = _wq.wcsd_profile_ragged(
             hub_delta, dist, wlev, tile_lo, tile_hi,
-            qidx, stile, ttile, first, num_rows=num_rows,
+            qidx, stile, ttile, num_rows=num_rows,
             num_levels=num_levels, interpret=interpret)
     else:
         bucket = _ref.wcsd_profile_ragged_compressed_ref(

@@ -90,29 +90,134 @@ def _fit_block(block_lt: int, Wt: int) -> int:
     return block_lt
 
 
+# ------------------------------------------------------------ output layout
+#
+# Every kernel below answers one scalar (or one [W + 1] bucket row) per
+# query, accumulated over many grid steps. The TPU lowering only accepts
+# blocks whose last two dims are (8, 128)-divisible or whole, so a
+# per-query (1, 1) output block cannot lower. Instead the whole output
+# stays resident in VMEM for the launch as lane-dense (8, 128) int32
+# tiles — query q lives at flat position q of tile q // 1024 — and is
+# written back to HBM once, after the last grid step. Shape
+# [levels, ceil(rows / 1024), 8, 128]; ``levels`` is 1 for single-level
+# queries and W + 1 for profiles.
+_OUT_TILE = 8 * 128
+
+
+def _out_shape(levels: int, rows: int):
+    return jax.ShapeDtypeStruct((levels, -(-rows // _OUT_TILE), 8, 128),
+                                jnp.int32)
+
+
+def _unpack_out(out, rows: int):
+    """[levels, R, 8, 128] kernel output -> [rows, levels] int32."""
+    return out.reshape(out.shape[0], -1)[:, :rows].T
+
+
+def _min_into(out_ref, q, best):
+    """``out[q] = min(out[q], best)`` for a (1, 1) ``best``: one masked
+    select over the (8, 128) tile that holds query ``q``."""
+    r = q // _OUT_TILE
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
+    hit = (sub == (q // 128) % 8) & (lane == q % 128)
+    cur = out_ref[r]
+    out_ref[r] = jnp.where(hit, jnp.minimum(cur, best), cur)
+
+
+def _join_into(out_ref, q, s_cells, t_cells, wq):
+    """The equality-gated min-plus of one (s, t) tile pair, folded into
+    query ``q``'s output. ``*_cells`` are (hub, dist, wlev) [1, n] int32
+    rows with dist already clamped to DEV_INF; pads carry hub -1 and
+    wlev -1. ``wq`` is the query level (single-level: cells below it are
+    masked) or None (profile: each meet's sum lands in the bucket of its
+    pair level ``min(wlev_s, wlev_t)``; the staircase scan runs in ops).
+    The s-side row is transposed to a column so the [n_s, n_t] compare
+    is a plain broadcast."""
+    hs, ds, ws = s_cells
+    ht, dt, wt = t_cells
+    if wq is not None:
+        ds = jnp.where(ws >= wq, ds, DEV_INF)
+        dt = jnp.where(wt >= wq, dt, DEV_INF)
+    dsum = jnp.where(hs.T == ht, ds.T + dt, DEV_INF)
+    if wq is not None:
+        _min_into(out_ref.at[0], q, jnp.min(dsum, keepdims=True))
+        return
+    mw = jnp.minimum(ws.T, wt)
+    for lev in range(out_ref.shape[0]):   # static: W + 1 is tiny
+        best = jnp.min(jnp.where(mw == lev, dsum, DEV_INF), keepdims=True)
+        _min_into(out_ref.at[lev], q, best)
+
+
 # --------------------------------------------------------------- segmented
-def _segmented_kernel(srow_ref, trow_ref, wq_ref,
-                      hs_ref, ds_ref, ws_ref, ht_ref, dt_ref, wt_ref,
-                      out_ref):
-    i, j = pl.program_id(0), pl.program_id(1)
+def _segmented_kernel(levels):
+    def kernel(*refs):
+        if levels is None:
+            srow_ref, trow_ref, wq_ref, *refs = refs
+        else:
+            srow_ref, trow_ref, *refs = refs
+        hs_ref, ds_ref, ws_ref, ht_ref, dt_ref, wt_ref, out_ref, \
+            s_buf, t_buf, sems = refs
+        i, a, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, DEV_INF)
+        @pl.when((i == 0) & (a == 0) & (b == 0))
+        def _init():
+            out_ref[...] = jnp.full(out_ref.shape, DEV_INF, jnp.int32)
 
-    wq = wq_ref[i]
-    # feasibility mask applied in-kernel: store pads carry wlev = -1 and
-    # real entries wlev >= 0, so one compare covers both in-bounds and
-    # quality-threshold masking (no count array on device).
-    hs = hs_ref[...]                                        # [1, Ws]
-    ds = jnp.where(ws_ref[...] >= wq,
-                   jnp.minimum(ds_ref[...], DEV_INF), DEV_INF)
-    ht = ht_ref[...]                                        # [1, bLt]
-    dt = jnp.where(wt_ref[...] >= wq,
-                   jnp.minimum(dt_ref[...], DEV_INF), DEV_INF)
-    eq = hs[0, :, None] == ht[0, None, :]                   # [Ws, bLt]
-    best = jnp.where(eq, ds[0, :, None] + dt[0, None, :], DEV_INF).min()
-    out_ref[0, 0] = jnp.minimum(out_ref[0, 0], best)
+        # chunk a of row srow[i] is row srow[i] * num_programs(1) + a of
+        # the chunk-major store view (see `_segmented_call`)
+        srow = srow_ref[i] * pl.num_programs(1) + a
+        trow = trow_ref[i] * pl.num_programs(2) + b
+        srcs = (hs_ref, ds_ref, ws_ref, ht_ref, dt_ref, wt_ref)
+        rows = (srow,) * 3 + (trow,) * 3
+        dsts = [s_buf.at[j] for j in range(3)] + [t_buf.at[j] for j in range(3)]
+        copies = [pltpu.make_async_copy(src.at[pl.ds(row, 1)], dst, sems.at[c])
+                  for c, (src, row, dst) in enumerate(zip(srcs, rows, dsts))]
+        for c in copies:
+            c.start()
+        for c in copies:
+            c.wait()
+
+        def cells(buf):
+            return buf[0], jnp.minimum(buf[1], DEV_INF), buf[2]
+
+        _join_into(out_ref, i, cells(s_buf), cells(t_buf),
+                   None if levels is not None else wq_ref[i])
+    return kernel
+
+
+def _segmented_call(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t, scalars,
+                    levels, block_lt, interpret):
+    """Shared launch of the two bucket-pair kernels. The bucket tiles are
+    viewed chunk-major ([N, W] -> [N * W / c, c], a row-major reshape), so
+    each grid step (query i, s-chunk a, t-chunk b) DMAs one whole row of
+    each view straight out of HBM — the query's row ids arrive by scalar
+    prefetch, so the gather IS the DMA — and folds the chunk pair's join
+    into the resident output."""
+    B = scalars[0].shape[0]
+    Ws, Wt = hub_s.shape[1], hub_t.shape[1]
+    cs, ct = _fit_block(block_lt, Ws), _fit_block(block_lt, Wt)
+    side_s = [a.reshape(-1, cs) for a in (hub_s, dist_s, wlev_s)]
+    side_t = [a.reshape(-1, ct) for a in (hub_t, dist_t, wlev_t)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(B, Ws // cs, Wt // ct),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 6,
+        out_specs=pl.BlockSpec(_out_shape(levels or 1, B).shape,
+                               lambda *_: (0, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((3, 1, cs), jnp.int32),
+                        pltpu.VMEM((3, 1, ct), jnp.int32),
+                        pltpu.SemaphoreType.DMA((6,))],
+    )
+    out = pl.pallas_call(
+        _segmented_kernel(levels),
+        grid_spec=grid_spec,
+        out_shape=_out_shape(levels or 1, B),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
+        interpret=interpret,
+    )(*scalars, *side_s, *side_t)
+    return _unpack_out(out, B)
 
 
 @functools.partial(jax.jit, static_argnames=("block_lt", "interpret"))
@@ -124,11 +229,12 @@ def wcsd_query_segmented(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t,
     Unlike `wcsd_query_gathered`, whose caller materializes [B, L] gathered
     + masked copies in HBM, this kernel reads label rows straight out of the
     bucket-tiled store: the query's row ids arrive as scalar-prefetch
-    arguments (`PrefetchScalarGridSpec`) and each BlockSpec index_map picks
-    block ``(srow[i], 0)`` / ``(trow[i], j)`` of the store, so the gather is
-    the DMA itself. Feasibility masking (wlev >= w) moves in-kernel, which
-    lets both query sides share one store — per query the HBM traffic is
-    3·(Ws + Wt) int32 instead of 4·2·L after host-side gather/mask.
+    arguments (`PrefetchScalarGridSpec`) and each grid step DMAs one
+    ``block_lt``-wide chunk of row ``srow[i]`` and one of row ``trow[i]``.
+    Feasibility masking (wlev >= w) happens in-kernel, which lets both
+    query sides share one store — per query the HBM traffic is
+    3·(Ws + Wt) int32 per chunk pair instead of 4·2·L after host-side
+    gather/mask.
 
     hub_s/dist_s/wlev_s: [Ns, Ws] s-side bucket tiles (pad: hub -1,
     wlev -1); hub_t/...: [Nt, Wt] t-side tiles. srow/trow/w_level: [B]
@@ -136,55 +242,95 @@ def wcsd_query_segmented(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t,
     pair does 1/64th the compares of a 1024-padded dense row pair).
     Returns [B] int32 best sums (>= DEV_INF means infeasible).
     """
-    B = srow.shape[0]
-    Ws, Wt = hub_s.shape[1], hub_t.shape[1]
-    block_lt = _fit_block(block_lt, Wt)
-    grid = (B, Wt // block_lt)
+    return _segmented_call(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t,
+                           (srow, trow, w_level), None, block_lt,
+                           interpret)[:, 0]
 
-    def s_spec():
-        return pl.BlockSpec((1, Ws), lambda i, j, srow, trow, wq: (srow[i], 0))
 
-    def t_spec():
-        return pl.BlockSpec((1, block_lt),
-                            lambda i, j, srow, trow, wq: (trow[i], j))
+@functools.partial(jax.jit, static_argnames=("num_levels", "block_lt",
+                                             "interpret"))
+def wcsd_profile_segmented(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t,
+                           srow, trow, *, num_levels: int,
+                           block_lt: int = 128, interpret: bool = True):
+    """One-pass profile queries: per-(vertex-pair) wlev-bucket minima.
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[s_spec(), s_spec(), s_spec(),
-                  t_spec(), t_spec(), t_spec()],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, srow, trow, wq: (i, 0)),
-    )
-    out = pl.pallas_call(
-        _segmented_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        interpret=interpret,
-    )(srow, trow, w_level, hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t)
-    return out[:, 0]
+    Same store layout and scalar-prefetch gather as `wcsd_query_segmented`,
+    but no per-query level: each query reads its two label rows ONCE and
+    bins every hub meet's distance sum by its pair level
+    ``min(wlev_s, wlev_t)``. Returns [B, num_levels + 1] int32 bucket
+    minima — ``out[b, l]`` is the best sum among pairs whose pair level
+    (the tightest constraint they satisfy) is exactly ``l``
+    (>= DEV_INF: none). The full
+    staircase ``dist(s, t, w)`` for every ``w`` is the suffix min-scan over
+    the level axis (`ops.wcsd_profile_segmented` applies it), making the
+    L-level workload one label sweep instead of L.
+    """
+    return _segmented_call(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t,
+                           (srow, trow), int(num_levels) + 1, block_lt,
+                           interpret)
 
 
 # ------------------------------------------------------------------ ragged
 #
 # The ragged kernels fetch their arena tiles MANUALLY: the arena stays in
-# HBM (`memory_space=ANY`) and each work item's six (1, lane) tiles are
-# DMA'd into a quad-buffered VMEM scratch ring (`_RAGGED_NBUF` slots x six
-# buffers, one DMA semaphore per copy). The automatic BlockSpec pipeline
-# only double-buffers and serializes its prefetch one grid step ahead;
-# with the explicit ring the copy for worklist entry k + 4 is issued the
-# moment slot k % 4 frees, so on skewed stores the O(lane^2) join of entry
-# k overlaps the HBM latency of the next THREE entries — deep enough to
-# hide a full tile fetch behind one join (the ROADMAP quad-buffering
-# item). Worklist scalars and tile spans still ride scalar prefetch; the
-# output side keeps its (qidx[k], 0) BlockSpec, so revisit-pipelining of
-# consecutive work items of one query is unchanged — and the whole flush
-# is still exactly ONE `pallas_call`.
+# HBM (`memory_space=ANY`) and each work item's six tiles are DMA'd into a
+# quad-buffered VMEM scratch ring (`_RAGGED_NBUF` slots x six buffers, one
+# DMA semaphore per copy). The automatic BlockSpec pipeline only
+# double-buffers and serializes its prefetch one grid step ahead; with the
+# explicit ring the copy for worklist entry k + 4 is issued the moment slot
+# k % 4 frees, so on skewed stores the O(lane^2) join of entry k overlaps
+# the HBM latency of the next THREE entries.
+#
+# Scalar prefetch carries one int32 per work item and array, and nothing
+# sized by the arena or the batch: the tile-span early-out and the query
+# level are gathered per work item on the XLA side (``ctl``), so the
+# kernel's SMEM footprint is a fixed number of words per work item. A
+# worklist longer than the SMEM budget admits runs as several launches of
+# equal length whose outputs are min-combined (exact: min is associative).
 _RAGGED_NBUF = 4
 
+# Scalar memory of one v5e TensorCore is 1 MiB. Worklist scalars may use
+# half of it; the rest is headroom for the kernel's own scalars.
+_SMEM_BYTES = 1 << 20
+_PREFETCH_BUDGET_WORDS = _SMEM_BYTES // 4 // 2
 
-def _fetch_ring(stile_ref, ttile_ref, srcs, bufs, sems):
+# The compressed arena's narrow dtypes are tiled 8 rows deep in HBM, so a
+# DMA can only start at a row multiple of 8: the kernel fetches the
+# aligned 8-tile group and picks its tile's row after widening.
+_ROW_GROUP = 8
+
+
+def pad_group_rows(hub, dist, wlev):
+    """Pad a compressed arena trio's tile rows to a multiple of
+    `_ROW_GROUP` (pad rows are never named by a worklist), so the aligned
+    group DMA stays in bounds. A no-op on aligned arenas; the engines pad
+    once at load so no flush pays the copy."""
+    T = hub.shape[0]
+    if T % _ROW_GROUP == 0:
+        return hub, dist, wlev
+    rows = ((0, -T % _ROW_GROUP), (0, 0))
+    return tuple(jnp.pad(a, rows) for a in (hub, dist, wlev))
+
+
+def ragged_launch_capacity(compressed: bool = False) -> int:
+    """Work items one ragged launch may hold: its scalar-prefetch words
+    (four per item, six on the compressed arena) within the SMEM budget."""
+    return _PREFETCH_BUDGET_WORDS // (6 if compressed else 4)
+
+
+def ragged_launches(worklist_len: int, compressed: bool = False
+                    ) -> tuple[int, int]:
+    """(launches, work items per launch) for a ragged worklist: ONE launch
+    whenever it fits `ragged_launch_capacity`, else the fewest equal
+    launches that each fit (the last is padded with no-op items)."""
+    n = max(1, -(-worklist_len // ragged_launch_capacity(compressed)))
+    return n, -(-worklist_len // n)
+
+
+def _fetch_ring(stile_ref, ttile_ref, srcs, bufs, sems, group):
     """DMA-descriptor factory for one worklist entry: six async copies
-    (s-side and t-side hub/dist/wlev tiles) into ring slot ``slot``.
+    (s-side and t-side hub/dist/wlev tiles, or their aligned ``group``
+    of tiles) into ring slot ``slot``.
 
     Start/wait calls must balance per (slot, copy) semaphore: every entry
     k is started exactly once (warmup for k < NBUF, else the prefetch at
@@ -192,8 +338,11 @@ def _fetch_ring(stile_ref, ttile_ref, srcs, bufs, sems):
     def copies(slot, entry):
         s = stile_ref[entry]
         t = ttile_ref[entry]
+        if group > 1:
+            s = pl.multiple_of(s // group * group, group)
+            t = pl.multiple_of(t // group * group, group)
         idxs = (s, s, s, t, t, t)
-        return [pltpu.make_async_copy(src.at[pl.ds(ix, 1)],
+        return [pltpu.make_async_copy(src.at[pl.ds(ix, group)],
                                       buf.at[slot], sems.at[slot, j])
                 for j, (src, ix, buf) in enumerate(zip(srcs, idxs, bufs))]
     return copies
@@ -227,78 +376,129 @@ def _fetch_next(k, WL, slot, copies, nbuf=_RAGGED_NBUF):
                 c.start()
 
 
-def _ragged_scratch(lane, dtypes, nbuf=_RAGGED_NBUF):
-    """Six (nbuf, 1, lane) VMEM ring buffers + the (nbuf, 6) DMA
-    semaphore array; ``dtypes`` is the (hub, dist, wlev) dtype triple
-    (int32 x3 uncompressed, int16/float/int8 compressed)."""
-    return ([pltpu.VMEM((nbuf, 1, lane), dt)
-             for dt in (*dtypes, *dtypes)]
-            + [pltpu.SemaphoreType.DMA((nbuf, 6))])
+def _decode_cells(hd, d, w, lo, row):
+    """In-register decode of one compressed arena tile (CompressedArena,
+    docs/index-format.md §6) from its fetched 8-tile group: widen, pick
+    row ``row``, then rebuild int16 hub deltas against the tile's lo rank
+    (the sign is the pad flag, so -1 sentinels survive), clamp bfloat16
+    distances at DEV_INF — the +inf pad encoding saturates there, so no
+    isfinite test is needed — and round back to int32 (+0.5 then
+    truncate; exact for every in-range integer the float format holds)."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, hd.shape, 0)
+
+    def pick(x):
+        return jnp.sum(jnp.where(sub == row, x, 0), axis=0, keepdims=True)
+
+    hd = pick(hd.astype(jnp.int32))
+    hub = jnp.where(hd >= 0, lo + hd, -1)
+    df = jnp.minimum(pick(d.astype(jnp.float32)), float(DEV_INF))
+    return hub, (df + 0.5).astype(jnp.int32), pick(w.astype(jnp.int32))
 
 
-def _ragged_kernel(WL, nbuf=_RAGGED_NBUF):
-    def kernel(qidx_ref, stile_ref, ttile_ref, first_ref, wq_ref,
-               lo_ref, hi_ref, hub_ref, dist_ref, wlev_ref, out_ref,
-               hs_buf, ds_buf, ws_buf, ht_buf, dt_buf, wt_buf, sems):
+def _ragged_kernel(WL, nbuf, single_level, compressed):
+    def kernel(*refs):
+        qidx_ref, stile_ref, ttile_ref, ctl_ref = refs[:4]
+        # compressed: the two tiles' hub bases ride along as scalars
+        slo_ref, tlo_ref = refs[4:6] if compressed else (None, None)
+        hub_ref, dist_ref, wlev_ref, out_ref, *bufs, sems = \
+            refs[6 if compressed else 4:]
         k = pl.program_id(0)
-        copies = _fetch_ring(stile_ref, ttile_ref,
-                             (hub_ref, dist_ref, wlev_ref) * 2,
-                             (hs_buf, ds_buf, ws_buf, ht_buf, dt_buf,
-                              wt_buf), sems)
-        slot = _fetch_wait(k, WL, copies, nbuf)
 
-        @pl.when(first_ref[k] == 1)
+        @pl.when(k == 0)
         def _init():
-            out_ref[...] = jnp.full_like(out_ref, DEV_INF)
+            out_ref[...] = jnp.full(out_ref.shape, DEV_INF, jnp.int32)
 
-        s_tile = stile_ref[k]
-        t_tile = ttile_ref[k]
+        copies = _fetch_ring(stile_ref, ttile_ref,
+                             (hub_ref, dist_ref, wlev_ref) * 2, bufs, sems,
+                             _ROW_GROUP if compressed else 1)
+        slot = _fetch_wait(k, WL, copies, nbuf)
+        ctl = ctl_ref[k]
+
         # Thm.-3 rows are hub-sorted, so each arena tile covers one
-        # hub-rank interval [lo, hi]; disjoint intervals cannot meet ->
-        # skip the O(lane^2) join for this work item (the DMA already
-        # happened, the saving is compute — and on skewed stores most
-        # cross-tile pairs of a long x long query are disjoint).
-        meet = (lo_ref[s_tile] <= hi_ref[t_tile]) & \
-            (lo_ref[t_tile] <= hi_ref[s_tile])
-
-        @pl.when(meet)
+        # hub-rank interval; ctl bit 0 says the two tiles' intervals
+        # intersect. Disjoint intervals cannot meet -> skip the O(lane^2)
+        # join (the DMA already happened, the saving is compute — and on
+        # skewed stores most cross-tile pairs of a long x long query are
+        # disjoint).
+        @pl.when((ctl & 1) == 1)
         def _join():
-            wq = wq_ref[qidx_ref[k]]
-            hs = hs_buf[slot]                               # [1, lane]
-            ds = jnp.where(ws_buf[slot] >= wq,
-                           jnp.minimum(ds_buf[slot], DEV_INF), DEV_INF)
-            ht = ht_buf[slot]                               # [1, lane]
-            dt = jnp.where(wt_buf[slot] >= wq,
-                           jnp.minimum(dt_buf[slot], DEV_INF), DEV_INF)
-            eq = hs[0, :, None] == ht[0, None, :]           # [lane, lane]
-            best = jnp.where(eq, ds[0, :, None] + dt[0, None, :],
-                             DEV_INF).min()
-            out_ref[0, 0] = jnp.minimum(out_ref[0, 0], best)
+            def cells(b0, lo_ref, tile_ref):
+                h, d, w = (b[slot] for b in bufs[b0:b0 + 3])
+                if compressed:
+                    return _decode_cells(h, d, w, lo_ref[k],
+                                         tile_ref[k] % _ROW_GROUP)
+                return h, jnp.minimum(d, DEV_INF), w
+
+            _join_into(out_ref, qidx_ref[k],
+                       cells(0, slo_ref, stile_ref),
+                       cells(3, tlo_ref, ttile_ref),
+                       ctl >> 1 if single_level else None)
 
         _fetch_next(k, WL, slot, copies, nbuf)
     return kernel
 
 
+def _ragged_call(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile,
+                 wq, num_rows, levels, interpret, nbuf):
+    """Shared launch of the four ragged kernels (plain / compressed x
+    single-level / profile). Builds the per-work-item scalars, splits the
+    worklist into SMEM-sized launches and min-combines their outputs.
+    Returns [num_rows, levels] int32 raw minima (>= DEV_INF: none)."""
+    compressed = hub.dtype != jnp.int32
+    meet = ((tile_lo[stile] <= tile_hi[ttile])
+            & (tile_lo[ttile] <= tile_hi[stile])).astype(jnp.int32)
+    ctl = meet if wq is None else (wq[qidx] << 1) | meet
+    scalars = [qidx, stile, ttile, ctl]
+    if compressed:
+        scalars += [tile_lo[stile], tile_lo[ttile]]
+        hub, dist, wlev = pad_group_rows(hub, dist, wlev)
+    n, L = ragged_launches(qidx.shape[0], compressed)
+    if n * L != qidx.shape[0]:
+        # no-op pads: ctl 0 never joins, row 0 / tile 0 are in bounds
+        scalars = [jnp.pad(a, (0, n * L - a.shape[0])) for a in scalars]
+    group = _ROW_GROUP if compressed else 1
+    lane = hub.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(L,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=pl.BlockSpec(_out_shape(levels, num_rows).shape,
+                               lambda *_: (0, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((nbuf, group, lane), a.dtype)
+                        for a in (hub, dist, wlev) * 2]
+        + [pltpu.SemaphoreType.DMA((nbuf, 6))],
+    )
+    kernel = _ragged_kernel(L, nbuf, wq is not None, compressed)
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    outs = [pl.pallas_call(kernel, grid_spec=grid_spec,
+                           out_shape=_out_shape(levels, num_rows),
+                           compiler_params=params, interpret=interpret)(
+                *(a[i * L:(i + 1) * L] for a in scalars), hub, dist, wlev)
+            for i in range(n)]
+    return _unpack_out(functools.reduce(jnp.minimum, outs), num_rows)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "nbuf"))
 def wcsd_query_ragged(hub, dist, wlev, tile_lo, tile_hi,
-                      qidx, stile, ttile, first, wq, *,
+                      qidx, stile, ttile, wq, *,
                       interpret: bool = True, nbuf: int = _RAGGED_NBUF):
-    """Single-launch ragged query path over the lane-tiled label arena.
+    """Ragged query path over the lane-tiled label arena.
 
-    Collapses the whole bucket-pair dispatch loop into ONE `pallas_call`:
-    the grid is a flat worklist of ``(query, s_tile, t_tile)`` work items
-    (one per tile pair of a query's two label rows, query-major — see
-    `core.query.emit_ragged_worklist`). The arena stays HBM-resident and
-    each entry's tiles are fetched through the quad-buffered DMA ring
-    (see the section comment), so a batch mixing every bucket length runs
-    in a single launch with zero wasted lanes and the tile DMA of entry
-    k + 4 overlapping the join of entry k.
+    Collapses the whole bucket-pair dispatch loop into ONE `pallas_call`
+    (several only when the worklist outgrows SMEM, see
+    `ragged_launches`): the grid is a flat worklist of
+    ``(query, s_tile, t_tile)`` work items (one per tile pair of a
+    query's two label rows — see `core.query.emit_ragged_worklist`). The
+    arena stays HBM-resident and each entry's tiles are fetched through
+    the quad-buffered DMA ring (see the section comment), so a batch
+    mixing every bucket length runs with zero wasted lanes and the tile
+    DMA of entry k + 4 overlapping the join of entry k.
 
-    hub/dist/wlev: [T, lane] arena tiles (pad contract hub -1, wlev -1);
-    tile_lo/tile_hi: [T] per-tile hub-rank spans (Thm.-3 early-out);
-    qidx/stile/ttile/first: [WL] int32 worklist — ``qidx`` is
-    non-decreasing (output rows are revisited only consecutively) and
-    ``first`` marks each query's first work item (DEV_INF init);
+    hub/dist/wlev: [T, lane] int32 arena tiles (pad contract hub -1,
+    wlev -1) — or the `CompressedArena` trio (int16 hub deltas, bfloat16
+    distances, int8 levels), decoded in-kernel; tile_lo/tile_hi: [T]
+    per-tile hub-rank spans (Thm.-3 early-out; tile_lo is also the hub
+    base of a compressed tile); qidx/stile/ttile: [WL] int32 worklist;
     wq: [Q] per-output-row query levels (worklist pads must point at a
     trash row whose level is infeasible). Returns [Q] int32 best sums
     (>= DEV_INF means infeasible).
@@ -307,327 +507,23 @@ def wcsd_query_ragged(hub, dist, wlev, tile_lo, tile_hi,
     the no-overlap baseline the serving bench's ``dma_overlap_speedup``
     row compares against.
     """
-    WL = qidx.shape[0]
-    Q = wq.shape[0]
-    lane = hub.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(WL,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=pl.BlockSpec(
-            (1, 1), lambda k, qidx, stile, ttile, first, wq, lo, hi:
-            (qidx[k], 0)),
-        scratch_shapes=_ragged_scratch(lane, (jnp.int32,) * 3, nbuf),
-    )
-    out = pl.pallas_call(
-        _ragged_kernel(WL, nbuf),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-        interpret=interpret,
-    )(qidx, stile, ttile, first, wq, tile_lo, tile_hi, hub, dist, wlev)
-    return out[:, 0]
-
-
-def _profile_ragged_kernel(WL, nbuf=_RAGGED_NBUF):
-    def kernel(qidx_ref, stile_ref, ttile_ref, first_ref, lo_ref, hi_ref,
-               hub_ref, dist_ref, wlev_ref, out_ref,
-               hs_buf, ds_buf, ws_buf, ht_buf, dt_buf, wt_buf, sems):
-        k = pl.program_id(0)
-        copies = _fetch_ring(stile_ref, ttile_ref,
-                             (hub_ref, dist_ref, wlev_ref) * 2,
-                             (hs_buf, ds_buf, ws_buf, ht_buf, dt_buf,
-                              wt_buf), sems)
-        slot = _fetch_wait(k, WL, copies, nbuf)
-
-        @pl.when(first_ref[k] == 1)
-        def _init():
-            out_ref[...] = jnp.full_like(out_ref, DEV_INF)
-
-        s_tile = stile_ref[k]
-        t_tile = ttile_ref[k]
-        meet = (lo_ref[s_tile] <= hi_ref[t_tile]) & \
-            (lo_ref[t_tile] <= hi_ref[s_tile])
-
-        @pl.when(meet)
-        def _join():
-            hs = hs_buf[slot]                               # [1, lane]
-            ds = jnp.minimum(ds_buf[slot], DEV_INF)
-            ht = ht_buf[slot]
-            dt = jnp.minimum(dt_buf[slot], DEV_INF)
-            eq = hs[0, :, None] == ht[0, None, :]           # [lane, lane]
-            dsum = jnp.where(eq, ds[0, :, None] + dt[0, None, :], DEV_INF)
-            mw = jnp.minimum(ws_buf[slot][0, :, None],
-                             wt_buf[slot][0, None, :])
-            for lev in range(out_ref.shape[1]):  # static: W + 1 is tiny
-                best = jnp.where(mw == lev, dsum, DEV_INF).min()
-                out_ref[0, lev] = jnp.minimum(out_ref[0, lev], best)
-
-        _fetch_next(k, WL, slot, copies, nbuf)
-    return kernel
+    return _ragged_call(hub, dist, wlev, tile_lo, tile_hi, qidx, stile,
+                        ttile, wq, wq.shape[0], 1, interpret, nbuf)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows", "num_levels",
                                              "interpret", "nbuf"))
 def wcsd_profile_ragged(hub, dist, wlev, tile_lo, tile_hi,
-                        qidx, stile, ttile, first, *, num_rows: int,
+                        qidx, stile, ttile, *, num_rows: int,
                         num_levels: int, interpret: bool = True,
                         nbuf: int = _RAGGED_NBUF):
-    """Single-launch ragged PROFILE path: same arena/worklist contract
-    (and quad-buffered tile fetch) as `wcsd_query_ragged`, no per-query
-    level — each work item bins its hub meets' distance sums by pair
-    level ``min(wlev_s, wlev_t)`` into the query's [num_levels + 1]
-    bucket row (the staircase is the suffix min-scan, applied in ops).
-    Returns [num_rows, num_levels + 1] int32 bucket minima; worklist pads
-    must point at trash row num_rows - 1."""
-    WL = qidx.shape[0]
-    lane = hub.shape[1]
-    Lp = int(num_levels) + 1
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(WL,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=pl.BlockSpec(
-            (1, Lp), lambda k, qidx, stile, ttile, first, lo, hi:
-            (qidx[k], 0)),
-        scratch_shapes=_ragged_scratch(lane, (jnp.int32,) * 3, nbuf),
-    )
-    return pl.pallas_call(
-        _profile_ragged_kernel(WL, nbuf),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_rows, Lp), jnp.int32),
-        interpret=interpret,
-    )(qidx, stile, ttile, first, tile_lo, tile_hi, hub, dist, wlev)
-
-
-# ------------------------------------------------- ragged, compressed arena
-def _decode_cells(hd, d, w, lo):
-    """In-register decode of one compressed arena tile (CompressedArena,
-    docs/index-format.md §6): int16 hub deltas rebuilt against the tile's
-    lo rank (the sign is the pad flag, so -1 sentinels survive), float
-    distances clamped at DEV_INF — the +inf pad encoding saturates there,
-    so no isfinite test is needed — and rounded back to int32 (+0.5 then
-    truncate; exact for every in-range integer the float format holds),
-    int8 quality levels widened."""
-    hub = jnp.where(hd >= 0, lo + hd.astype(jnp.int32), -1)
-    dist = (jnp.minimum(d.astype(jnp.float32), float(DEV_INF))
-            + 0.5).astype(jnp.int32)
-    return hub, dist, w.astype(jnp.int32)
-
-
-def _ragged_kernel_c(WL, nbuf=_RAGGED_NBUF):
-    def kernel(qidx_ref, stile_ref, ttile_ref, first_ref, wq_ref,
-               lo_ref, hi_ref, hub_ref, dist_ref, wlev_ref, out_ref,
-               hs_buf, ds_buf, ws_buf, ht_buf, dt_buf, wt_buf, sems):
-        k = pl.program_id(0)
-        copies = _fetch_ring(stile_ref, ttile_ref,
-                             (hub_ref, dist_ref, wlev_ref) * 2,
-                             (hs_buf, ds_buf, ws_buf, ht_buf, dt_buf,
-                              wt_buf), sems)
-        slot = _fetch_wait(k, WL, copies, nbuf)
-
-        @pl.when(first_ref[k] == 1)
-        def _init():
-            out_ref[...] = jnp.full_like(out_ref, DEV_INF)
-
-        s_tile = stile_ref[k]
-        t_tile = ttile_ref[k]
-        meet = (lo_ref[s_tile] <= hi_ref[t_tile]) & \
-            (lo_ref[t_tile] <= hi_ref[s_tile])
-
-        @pl.when(meet)
-        def _join():
-            wq = wq_ref[qidx_ref[k]]
-            hs, ds0, ws = _decode_cells(hs_buf[slot], ds_buf[slot],
-                                        ws_buf[slot], lo_ref[s_tile])
-            ht, dt0, wt = _decode_cells(ht_buf[slot], dt_buf[slot],
-                                        wt_buf[slot], lo_ref[t_tile])
-            ds = jnp.where(ws >= wq, ds0, DEV_INF)
-            dt = jnp.where(wt >= wq, dt0, DEV_INF)
-            eq = hs[0, :, None] == ht[0, None, :]
-            best = jnp.where(eq, ds[0, :, None] + dt[0, None, :],
-                             DEV_INF).min()
-            out_ref[0, 0] = jnp.minimum(out_ref[0, 0], best)
-
-        _fetch_next(k, WL, slot, copies, nbuf)
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "nbuf"))
-def wcsd_query_ragged_compressed(hub_delta, dist, wlev, tile_lo, tile_hi,
-                                 qidx, stile, ttile, first, wq, *,
-                                 interpret: bool = True,
-                                 nbuf: int = _RAGGED_NBUF):
-    """`wcsd_query_ragged` over the COMPRESSED arena: identical worklist
-    and output contract, but the tiles arrive as int16 hub deltas /
-    bf16-or-fp16 distances / int8 levels — the quad-buffered ring scratch
-    holds the narrow dtypes, so the DMA per work item shrinks with the
-    store — and are decoded in-register (`_decode_cells`). Callers must
-    not pass overflowed stores (CompressedArena.overflow) — the engines
-    fall back to the uncompressed arena for those."""
-    WL = qidx.shape[0]
-    Q = wq.shape[0]
-    lane = hub_delta.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(WL,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=pl.BlockSpec(
-            (1, 1), lambda k, qidx, stile, ttile, first, wq, lo, hi:
-            (qidx[k], 0)),
-        scratch_shapes=_ragged_scratch(
-            lane, (hub_delta.dtype, dist.dtype, wlev.dtype), nbuf),
-    )
-    out = pl.pallas_call(
-        _ragged_kernel_c(WL, nbuf),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, 1), jnp.int32),
-        interpret=interpret,
-    )(qidx, stile, ttile, first, wq, tile_lo, tile_hi,
-      hub_delta, dist, wlev)
-    return out[:, 0]
-
-
-def _profile_ragged_kernel_c(WL, nbuf=_RAGGED_NBUF):
-    def kernel(qidx_ref, stile_ref, ttile_ref, first_ref, lo_ref, hi_ref,
-               hub_ref, dist_ref, wlev_ref, out_ref,
-               hs_buf, ds_buf, ws_buf, ht_buf, dt_buf, wt_buf, sems):
-        k = pl.program_id(0)
-        copies = _fetch_ring(stile_ref, ttile_ref,
-                             (hub_ref, dist_ref, wlev_ref) * 2,
-                             (hs_buf, ds_buf, ws_buf, ht_buf, dt_buf,
-                              wt_buf), sems)
-        slot = _fetch_wait(k, WL, copies, nbuf)
-
-        @pl.when(first_ref[k] == 1)
-        def _init():
-            out_ref[...] = jnp.full_like(out_ref, DEV_INF)
-
-        s_tile = stile_ref[k]
-        t_tile = ttile_ref[k]
-        meet = (lo_ref[s_tile] <= hi_ref[t_tile]) & \
-            (lo_ref[t_tile] <= hi_ref[s_tile])
-
-        @pl.when(meet)
-        def _join():
-            hs, ds, ws = _decode_cells(hs_buf[slot], ds_buf[slot],
-                                       ws_buf[slot], lo_ref[s_tile])
-            ht, dt, wt = _decode_cells(ht_buf[slot], dt_buf[slot],
-                                       wt_buf[slot], lo_ref[t_tile])
-            eq = hs[0, :, None] == ht[0, None, :]
-            dsum = jnp.where(eq, ds[0, :, None] + dt[0, None, :], DEV_INF)
-            mw = jnp.minimum(ws[0, :, None], wt[0, None, :])
-            for lev in range(out_ref.shape[1]):  # static: W + 1 is tiny
-                best = jnp.where(mw == lev, dsum, DEV_INF).min()
-                out_ref[0, lev] = jnp.minimum(out_ref[0, lev], best)
-
-        _fetch_next(k, WL, slot, copies, nbuf)
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("num_rows", "num_levels",
-                                             "interpret", "nbuf"))
-def wcsd_profile_ragged_compressed(hub_delta, dist, wlev, tile_lo, tile_hi,
-                                   qidx, stile, ttile, first, *,
-                                   num_rows: int, num_levels: int,
-                                   interpret: bool = True,
-                                   nbuf: int = _RAGGED_NBUF):
-    """`wcsd_profile_ragged` over the COMPRESSED arena (see
-    `wcsd_query_ragged_compressed` for the decode contract)."""
-    WL = qidx.shape[0]
-    lane = hub_delta.shape[1]
-    Lp = int(num_levels) + 1
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(WL,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=pl.BlockSpec(
-            (1, Lp), lambda k, qidx, stile, ttile, first, lo, hi:
-            (qidx[k], 0)),
-        scratch_shapes=_ragged_scratch(
-            lane, (hub_delta.dtype, dist.dtype, wlev.dtype), nbuf),
-    )
-    return pl.pallas_call(
-        _profile_ragged_kernel_c(WL, nbuf),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_rows, Lp), jnp.int32),
-        interpret=interpret,
-    )(qidx, stile, ttile, first, tile_lo, tile_hi,
-      hub_delta, dist, wlev)
-
-
-# ----------------------------------------------------------------- profile
-def _profile_kernel(srow_ref, trow_ref,
-                    hs_ref, ds_ref, ws_ref, ht_ref, dt_ref, wt_ref,
-                    out_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, DEV_INF)
-
-    # one gather of each side per query, every level answered from it: a
-    # meeting pair (i, j) is feasible at every level <= min(ws[i], wt[j]),
-    # so the pair contributes its distance sum to exactly one wlev BUCKET
-    # (its pair level); the suffix min-scan over buckets -> staircase runs
-    # in the wrapper, after all t-tiles have accumulated. Store pads carry
-    # wlev = -1, below every bucket, so they never contribute.
-    hs = hs_ref[...]                                        # [1, Ws]
-    ds = jnp.minimum(ds_ref[...], DEV_INF)
-    ht = ht_ref[...]                                        # [1, bLt]
-    dt = jnp.minimum(dt_ref[...], DEV_INF)
-    eq = hs[0, :, None] == ht[0, None, :]                   # [Ws, bLt]
-    dsum = jnp.where(eq, ds[0, :, None] + dt[0, None, :], DEV_INF)
-    mw = jnp.minimum(ws_ref[...][0, :, None], wt_ref[...][0, None, :])
-    for lev in range(out_ref.shape[1]):   # static unroll: W + 1 is tiny
-        best = jnp.where(mw == lev, dsum, DEV_INF).min()
-        out_ref[0, lev] = jnp.minimum(out_ref[0, lev], best)
-
-
-@functools.partial(jax.jit, static_argnames=("num_levels", "block_lt",
-                                             "interpret"))
-def wcsd_profile_segmented(hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t,
-                           srow, trow, *, num_levels: int,
-                           block_lt: int = 128, interpret: bool = True):
-    """One-pass profile queries: per-(vertex-pair) wlev-bucket minima.
-
-    Same store layout and scalar-prefetch gather as `wcsd_query_segmented`,
-    but no per-query level: each query reads its two label rows ONCE and
-    bins every hub meet's distance sum by its pair level
-    ``min(wlev_s, wlev_t)``. Returns [B, num_levels + 1] int32 bucket
-    minima — ``out[b, l]`` is the best sum among pairs whose pair level
-    (the tightest constraint they satisfy) is exactly ``l``
-    (>= DEV_INF: none). The full
-    staircase ``dist(s, t, w)`` for every ``w`` is the suffix min-scan over
-    the level axis (`ops.wcsd_profile_segmented` applies it), making the
-    L-level workload one label sweep instead of L.
-
-    The [B, num_levels + 1] output block is narrow (not lane-aligned);
-    that is fine — it is DEV_INF-initialized per query and scalar-
-    accumulated, exactly like the [B, 1] block of the single-level kernel.
-    """
-    B = srow.shape[0]
-    Ws, Wt = hub_s.shape[1], hub_t.shape[1]
-    Lp = int(num_levels) + 1
-    block_lt = _fit_block(block_lt, Wt)
-    grid = (B, Wt // block_lt)
-
-    def s_spec():
-        return pl.BlockSpec((1, Ws), lambda i, j, srow, trow: (srow[i], 0))
-
-    def t_spec():
-        return pl.BlockSpec((1, block_lt),
-                            lambda i, j, srow, trow: (trow[i], j))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[s_spec(), s_spec(), s_spec(),
-                  t_spec(), t_spec(), t_spec()],
-        out_specs=pl.BlockSpec((1, Lp), lambda i, j, srow, trow: (i, 0)),
-    )
-    return pl.pallas_call(
-        _profile_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Lp), jnp.int32),
-        interpret=interpret,
-    )(srow, trow, hub_s, dist_s, wlev_s, hub_t, dist_t, wlev_t)
+    """Ragged PROFILE path: same arena/worklist contract (plain or
+    compressed trio, quad-buffered tile fetch, SMEM split) as
+    `wcsd_query_ragged`, no per-query level — each work item bins its hub
+    meets' distance sums by pair level ``min(wlev_s, wlev_t)`` into the
+    query's [num_levels + 1] bucket row (the staircase is the suffix
+    min-scan, applied in ops). Returns [num_rows, num_levels + 1] int32
+    bucket minima; worklist pads must point at trash row num_rows - 1."""
+    return _ragged_call(hub, dist, wlev, tile_lo, tile_hi, qidx, stile,
+                        ttile, None, num_rows, int(num_levels) + 1,
+                        interpret, nbuf)

@@ -3,6 +3,10 @@ import sys
 
 import pytest
 
+# the suite runs on the CPU backend with interpret-mode kernels, also on a
+# TPU host (the chip path is chip_smoke.py); set before any test imports jax
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 # src layout import without install; tests dir for the _hypo_shim helper
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))

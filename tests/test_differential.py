@@ -286,16 +286,14 @@ def test_unreachable_and_identity_on_packed_index():
 
 # ------------------------------------------- row-sharded ragged (8 devices)
 _SHARDED_DIFFERENTIAL_PROG = r'''
-import os, tempfile
+import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 # the 200 instances reuse a handful of grid shapes (V in {8,10,12}, W in
 # {2,3}); the persistent cache turns the per-instance engine compiles into
 # disk hits, keeping the full sweep CI-sized
-jax.config.update("jax_compilation_cache_dir",
-                  tempfile.mkdtemp(prefix="wcsd-diff-cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro.launch.compile_cache import enable_compile_cache
+enable_compile_cache()
 import numpy as np
 from repro.core.baselines import constrained_distance_grid
 from repro.core.generators import erdos_renyi

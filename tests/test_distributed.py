@@ -6,17 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# these tests drive the explicit-mesh API surface (jax.sharding.AxisType,
-# jax.set_mesh, jax.shard_map); on older jax the APIs do not exist at all,
-# so gate instead of failing on an AttributeError in the subprocess
-pytestmark = pytest.mark.skipif(
-    not (hasattr(jax.sharding, "AxisType") and hasattr(jax, "set_mesh")
-         and hasattr(jax, "shard_map")),
-    reason="needs jax explicit-mesh APIs (AxisType/set_mesh/shard_map)")
-
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 

@@ -171,13 +171,11 @@ def test_differential_coverage_target():
 
 # ------------------------------------------- sharded modes (8 devices)
 _SHARDED_DYNAMIC_PROG = r'''
-import os, tempfile
+import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
-jax.config.update("jax_compilation_cache_dir",
-                  tempfile.mkdtemp(prefix="wcsd-dyn-cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro.launch.compile_cache import enable_compile_cache
+enable_compile_cache()
 import numpy as np
 from repro.core.baselines import constrained_distance_grid
 from repro.core.generators import erdos_renyi

@@ -216,6 +216,62 @@ def test_one_pallas_launch_per_flush():
     np.testing.assert_array_equal(exp_bp, exp)
 
 
+def test_flush_over_smem_budget_splits_into_launches(monkeypatch):
+    """A flush whose worklist scalars exceed the per-launch SMEM budget
+    runs as several equal launches — through `DeviceQueryEngine` and
+    `WCSDServer` alike — whose min-combined answers equal
+    `query_batch_jnp` / `profile_batch_jnp` exactly; the launch count is
+    the worklist length over the per-launch capacity, rounded up."""
+    import repro.kernels.wcsd_query as wq
+    from repro.core.query import profile_batch_jnp, query_batch_jnp
+
+    g = erdos_renyi(60, 4.0, num_levels=4, seed=77)
+    idx = build_wc_index(g)
+    B = 256                                   # a power of two: no pad lanes
+    # distinct undirected keys, so the server's memo folds none of them
+    keys = np.random.default_rng(5).permutation(
+        [(a, b, w) for a in range(g.num_nodes) for b in range(a, g.num_nodes)
+         for w in range(g.num_levels + 1)])[:B].astype(np.int32)
+    s, t, wl = keys.T
+    labels = (idx.hub_rank, idx.dist, idx.wlev, idx.count)
+    exp = np.asarray(query_batch_jnp(*labels, s, t, wl))
+    exp_prof = np.asarray(profile_batch_jnp(*labels, s, t,
+                                            num_levels=g.num_levels))
+    per_launch = 48
+    # four scalar arrays per work item on the uncompressed arena
+    monkeypatch.setattr(wq, "_PREFETCH_BUDGET_WORDS", 4 * per_launch)
+    tile_cnt = idx.packed().arena().tile_cnt
+    WL = ragged_worklist_len(tile_cnt, s, t)
+    want = -(-WL // per_launch)
+    assert want > 1
+
+    launches = []
+    real = wq.pl.pallas_call
+
+    def counting(*a, **k):
+        launches.append(a)
+        return real(*a, **k)
+
+    monkeypatch.setattr(wq.pl, "pallas_call", counting)
+    jax.clear_caches()          # re-trace under the shrunken budget
+    try:
+        eng = DeviceQueryEngine(idx, layout="csr", use_pallas=True)
+        np.testing.assert_array_equal(np.asarray(eng.query(s, t, wl)), exp)
+        assert len(launches) == want
+        launches.clear()
+        np.testing.assert_array_equal(eng.query_profile(s, t), exp_prof)
+        assert len(launches) == want
+        launches.clear()
+        jax.clear_caches()      # same flush shape: trace it again
+        srv = WCSDServer(idx, layout="csr", use_pallas=True, max_batch=B,
+                         backend="device")
+        np.testing.assert_array_equal(srv.query_many(s, t, wl), exp)
+        assert srv.stats.batches == 1 and len(launches) == want
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()      # later tests must not reuse these traces
+
+
 def test_rowsharded_one_launch_one_collective_per_flush():
     """Acceptance for the ROW-SHARDED ragged path, on 8 virtual devices
     (subprocess — the device count must be fixed before jax initializes):
@@ -455,7 +511,7 @@ def test_emit_ragged_worklist_matches_numpy_reference():
     WL = ragged_worklist_len(tile_cnt, s, t)
     assert WL >= total and WL & (WL - 1) == 0
 
-    qidx, stile, ttile, first = (np.asarray(a) for a in emit_ragged_worklist(
+    qidx, stile, ttile = (np.asarray(a) for a in emit_ragged_worklist(
         jnp.asarray(tile_base), jnp.asarray(tile_cnt),
         jnp.asarray(s), jnp.asarray(t), worklist_len=WL))
     # numpy reference: query-major expansion of every tile pair
@@ -467,16 +523,10 @@ def test_emit_ragged_worklist_matches_numpy_reference():
     np.testing.assert_array_equal(qidx[:total], exp_q)
     np.testing.assert_array_equal(stile[:total], exp_s)
     np.testing.assert_array_equal(ttile[:total], exp_t)
-    # first marks each output row's first work item, exactly once per row
-    np.testing.assert_array_equal(
-        np.flatnonzero(first[:total]),
-        np.concatenate([[0], 1 + np.flatnonzero(np.diff(exp_q))]))
-    # pads: trash row Q, tile 0, and the trash row is init'd too
+    # pads: trash row Q, tile 0
     assert np.all(qidx[total:] == Q)
     assert np.all(stile[total:] == 0) and np.all(ttile[total:] == 0)
-    if WL > total:
-        assert first[total] == 1
-    # qidx non-decreasing: output blocks are revisited only consecutively
+    # query-major: each query's work items are consecutive
     assert np.all(np.diff(qidx.astype(np.int64)) >= 0)
 
 

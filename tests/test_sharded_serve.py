@@ -99,7 +99,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.core.query import shard_map_compat
 from repro.distributed.collectives import (row_gather_psum,
                                            row_gather_psum_scatter)
 from repro.launch.mesh import make_serving_mesh
@@ -109,13 +108,13 @@ rng = np.random.default_rng(0)
 store = rng.integers(-5, 100, (V, W)).astype(np.int32)
 rows = rng.integers(0, V, B).astype(np.int32)
 per = V // 8
-f = jax.jit(shard_map_compat(
+f = jax.jit(jax.shard_map(
     lambda sh, rr: row_gather_psum(sh, rr, ("data",), per),
-    mesh, (P("data", None), P(None)), P(None)))
+    mesh=mesh, in_specs=(P("data", None), P(None)), out_specs=P(None)))
 np.testing.assert_array_equal(np.asarray(f(store, rows)), store[rows])
-g = jax.jit(shard_map_compat(
+g = jax.jit(jax.shard_map(
     lambda sh, rr: row_gather_psum_scatter(sh, rr, ("data",), per),
-    mesh, (P("data", None), P(None)), P("data")))
+    mesh=mesh, in_specs=(P("data", None), P(None)), out_specs=P("data")))
 np.testing.assert_array_equal(np.asarray(g(store, rows)), store[rows])
 print("OK row gather")
 
@@ -124,10 +123,11 @@ print("OK row gather")
 from repro.distributed.collectives import multi_row_gather_psum_scatter
 store2 = rng.integers(0, 7, (V, 3)).astype(np.int32)
 col = rng.integers(1, 50, (V, 1)).astype(np.int32)
-m = jax.jit(shard_map_compat(
+m = jax.jit(jax.shard_map(
     lambda a, b, c, rr: multi_row_gather_psum_scatter(
         (a, b, c), rr, ("data",), per),
-    mesh, (P("data", None),) * 3 + (P(None),), (P("data"),) * 3))
+    mesh=mesh, in_specs=(P("data", None),) * 3 + (P(None),),
+    out_specs=(P("data"),) * 3))
 ga, gb, gc = (np.asarray(x) for x in m(store, store2, col, rows))
 np.testing.assert_array_equal(ga, store[rows])
 np.testing.assert_array_equal(gb, store2[rows])
