@@ -304,6 +304,7 @@ def _bench_continuous_batching(idx, s, t, wl, name, batch=1024):
     pathological serialization (a flush that re-runs the backlog, a
     request parked forever), not a machine-speed gate — hence its slack."""
     srv = WCSDServer(idx, max_batch=256, max_wait_us=500.0, min_batch=16)
+    srv.tracer.start()
     # warm the compile cache by STREAMING (not bulk query_many): deadline
     # flushes compile the small padded shapes the measured epoch will
     # hit, not just the max_batch one
@@ -315,7 +316,7 @@ def _bench_continuous_batching(idx, s, t, wl, name, batch=1024):
     srv.flush()
     for r in wrids:
         srv.result(r)
-    srv.latencies_us.clear()
+    srv.tracer.reset()
     lo, hi = warm, warm + batch
     rids = [None] * (hi - lo)
     for i, (a, b, c) in enumerate(zip(s[lo:hi], t[lo:hi], wl[lo:hi])):

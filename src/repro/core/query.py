@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.wcsd_query import pad_group_rows
+from . import tracing
 from .graph import INF_DIST
 from .wc_index import (PackedLabels, PackedWCIndex, WCIndex, ceil_to,
                        round_to_lane, round_to_pow2)
@@ -243,16 +244,19 @@ def ragged_query_batch(hub, dist, wlev, tile_lo, tile_hi,
     then be the compressed trio, the index arrays are shared."""
     from ..kernels import ops as kops
     s, t, wl = stq[0], stq[1], stq[2]
-    qidx, stile, ttile = emit_ragged_worklist(
-        tile_base, tile_cnt, s, t, worklist_len=worklist_len)
-    # one trash output row for worklist pads; no stored wlev reaches 2^20,
-    # so its level is infeasible at every entry
-    wq = jnp.concatenate([wl, jnp.full((1,), 1 << 20, jnp.int32)])
-    op = (kops.wcsd_query_ragged_compressed if compressed
-          else kops.wcsd_query_ragged)
-    out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile,
-             wq, interpret=interpret, use_kernel=use_kernel)
-    return out[: s.shape[0]]
+    with jax.named_scope("wcsd.emit_worklist"):
+        qidx, stile, ttile = emit_ragged_worklist(
+            tile_base, tile_cnt, s, t, worklist_len=worklist_len)
+    with jax.named_scope("wcsd.join"):
+        # one trash output row for worklist pads; no stored wlev reaches
+        # 2^20, so its level is infeasible at every entry
+        wq = jnp.concatenate([wl, jnp.full((1,), 1 << 20, jnp.int32)])
+        op = (kops.wcsd_query_ragged_compressed if compressed
+              else kops.wcsd_query_ragged)
+        out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile,
+                 wq, interpret=interpret, use_kernel=use_kernel)
+    with jax.named_scope("wcsd.unstage"):
+        return out[: s.shape[0]]
 
 
 @functools.partial(jax.jit, static_argnames=("worklist_len", "num_levels",
@@ -267,14 +271,17 @@ def ragged_profile_batch(hub, dist, wlev, tile_lo, tile_hi,
     Returns [Q, num_levels + 1] staircases."""
     from ..kernels import ops as kops
     s, t = stq[0], stq[1]
-    qidx, stile, ttile = emit_ragged_worklist(
-        tile_base, tile_cnt, s, t, worklist_len=worklist_len)
-    op = (kops.wcsd_profile_ragged_compressed if compressed
-          else kops.wcsd_profile_ragged)
-    out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile,
-             num_rows=int(s.shape[0]) + 1, num_levels=num_levels,
-             interpret=interpret, use_kernel=use_kernel)
-    return out[: s.shape[0]]
+    with jax.named_scope("wcsd.emit_worklist"):
+        qidx, stile, ttile = emit_ragged_worklist(
+            tile_base, tile_cnt, s, t, worklist_len=worklist_len)
+    with jax.named_scope("wcsd.join"):
+        op = (kops.wcsd_profile_ragged_compressed if compressed
+              else kops.wcsd_profile_ragged)
+        out = op(hub, dist, wlev, tile_lo, tile_hi, qidx, stile, ttile,
+                 num_rows=int(s.shape[0]) + 1, num_levels=num_levels,
+                 interpret=interpret, use_kernel=use_kernel)
+    with jax.named_scope("wcsd.unstage"):
+        return out[: s.shape[0]]
 
 
 class PendingResult:
@@ -355,7 +362,13 @@ class _QueryEngineBase:
     """Shared engine plumbing: the host-side bucket-pair plan / pad /
     dispatch / assemble loop of the CSR layout, and quality-threshold
     canonicalization. Subclasses provide ``_bucket_of`` / ``_slot_of`` /
-    ``num_buckets`` / ``num_levels`` and a per-sub-batch dispatch."""
+    ``num_buckets`` / ``num_levels`` and a per-sub-batch dispatch.
+
+    A `WCSDServer` hands every engine it builds its tracer and its
+    `ServeStats` (``serve_stats``, for the ``new_programs`` count)."""
+
+    tracer = tracing.OFF
+    serve_stats = None
 
     def _plan_segmented(self, s, t, w_level, pad_len, dispatch
                         ) -> PendingResult:
@@ -407,6 +420,35 @@ class _QueryEngineBase:
         return PendingResult(assemble, deps=[r for _, r in parts])
 
     # ----------------------------------------------------- ragged dispatch
+    def _planned(self, t0: int, key: tuple) -> tuple[int, bool]:
+        """After one ragged flush's host plan (begun at ``t0``): count
+        ``key`` — (profile, padded Q, worklist length, gather capacity),
+        every static the flush program is specialised on — as a new
+        program the first time this engine meets it, and close the plan
+        span. Returns (launch start, new)."""
+        new = key not in self._shapes
+        if new:
+            self._shapes.add(key)
+            if self.serve_stats is not None:
+                self.serve_stats.new_programs += 1
+        tr = self.tracer
+        if not tr.on:
+            return 0, new
+        t1 = tracing.now()
+        tr.span(tracing.PLAN, t0, t1)
+        tr.shape(key[1], key[2])
+        return t1, new
+
+    def _launched(self, t1: int, new: bool) -> None:
+        """After the device_put and jitted call of a flush begun at
+        ``t1``; the first call of a new shape also traces and compiles."""
+        tr = self.tracer
+        if tr.on:
+            t2 = tracing.now()
+            tr.span(tracing.LAUNCH, t1, t2)
+            if new:
+                tr.span(tracing.BUILD, t1, t2)
+
     def _stage_ragged(self, s, t, w_level=None):
         """Staged query array for one ragged flush: queries padded by the
         engine's batch rule, stacked into one [3 or 2, Q] H2D staging
@@ -478,6 +520,7 @@ class DeviceQueryEngine(_QueryEngineBase):
         self.num_levels = idx.num_levels
         self.compressed = False
         self.compression_overflow = False
+        self._shapes: set = set()   # ragged flush shapes dispatched
         if layout == "csr":
             from .wc_index import LANE
             lane = LANE if lane is None else int(lane)
@@ -551,17 +594,20 @@ class DeviceQueryEngine(_QueryEngineBase):
     _ragged_pad = staticmethod(round_to_pow2)
 
     def _query_ragged_async(self, s, t, w_level) -> PendingResult:
+        t0 = tracing.now() if self.tracer.on else 0
         s = np.asarray(s, np.int32)
         t = np.asarray(t, np.int32)
         w_level = np.asarray(w_level, np.int32)
         n = len(s)
         stq = self._stage_ragged(s, t, w_level)
         wl_len = ragged_worklist_len(self._tile_cnt_np, stq[0], stq[1])
+        t1, new = self._planned(t0, (False, stq.shape[1], wl_len, None))
         res = ragged_query_batch(*self._arena, jnp.asarray(stq),
                                  worklist_len=wl_len,
                                  interpret=self.interpret,
                                  use_kernel=self.use_pallas,
                                  compressed=self.compressed)
+        self._launched(t1, new)
         return PendingResult(lambda: np.asarray(res)[:n], deps=(res,))
 
     def _query_segmented_async(self, s, t, w_level) -> PendingResult:
@@ -604,17 +650,20 @@ class DeviceQueryEngine(_QueryEngineBase):
                                  s, t, num_levels=self.num_levels)
 
     def _profile_ragged_async(self, s, t) -> PendingResult:
+        t0 = tracing.now() if self.tracer.on else 0
         s = np.asarray(s, np.int32)
         t = np.asarray(t, np.int32)
         n = len(s)
         stq = self._stage_ragged(s, t)
         wl_len = ragged_worklist_len(self._tile_cnt_np, stq[0], stq[1])
+        t1, new = self._planned(t0, (True, stq.shape[1], wl_len, None))
         res = ragged_profile_batch(*self._arena, jnp.asarray(stq),
                                    worklist_len=wl_len,
                                    num_levels=self.num_levels,
                                    interpret=self.interpret,
                                    use_kernel=self.use_pallas,
                                    compressed=self.compressed)
+        self._launched(t1, new)
         return PendingResult(lambda: np.asarray(res)[:n], deps=(res,))
 
     def _profile_segmented_async(self, s, t) -> PendingResult:
@@ -717,6 +766,7 @@ class ShardedQueryEngine(_QueryEngineBase):
         # own batch slice of the gathered rows
         self._qreplicated = NamedSharding(mesh, P(None))
         self._fns: dict = {}  # jitted shard_map callables, one per path
+        self._shapes: set = set()   # ragged flush shapes dispatched
 
         if compressed and (layout, dispatch) != ("csr", "ragged"):
             raise ValueError("compressed=True requires layout='csr' with "
@@ -1057,15 +1107,18 @@ class ShardedQueryEngine(_QueryEngineBase):
         return uniq, G
 
     def _query_ragged_async(self, s, t, w_level) -> PendingResult:
+        t0 = tracing.now() if self.tracer.on else 0
         n = len(s)
         stq = self._stage_ragged(s, t, w_level)
         if self.mode == "sharded_labels":
             stq, perm = self._balance_ragged(stq)
             wl_len = self._balanced_worklist_len(stq)
             uniq, G = self._gather_plan(stq, wl_len)
+            t1, new = self._planned(t0, (False, stq.shape[1], wl_len, G))
             fn = self._ragged_fn(wl_len, profile=False, gather_cap=G)
             res = fn(*self._arena, self._put_staged(stq),
                      self._put_staged(uniq))
+            self._launched(t1, new)
 
             def finalize():
                 out = np.empty(stq.shape[1], dtype=np.int32)
@@ -1073,8 +1126,11 @@ class ShardedQueryEngine(_QueryEngineBase):
                 return out[:n]
 
             return PendingResult(finalize, deps=(res,))
-        fn = self._ragged_fn(self._shard_worklist_len(stq), profile=False)
+        wl_len = self._shard_worklist_len(stq)
+        t1, new = self._planned(t0, (False, stq.shape[1], wl_len, None))
+        fn = self._ragged_fn(wl_len, profile=False)
         res = fn(*self._arena, self._put_staged(stq))
+        self._launched(t1, new)
         return PendingResult(lambda: np.asarray(res)[:n], deps=(res,))
 
     def _ragged_fn(self, worklist_len: int, profile: bool,
@@ -1134,42 +1190,49 @@ class ShardedQueryEngine(_QueryEngineBase):
                                                        ragged_tile_gather)
                 from ..kernels import ops as kops
                 b = stq.shape[1] // ndev
-                # one fused reduce-scatter routes each device's
-                # host-planned DISTINCT tile list to it, in linear device
-                # order — each tile crosses the interconnect once
-                gh, gd, gw = ragged_tile_gather(
-                    (hub, dist, wlev), uniq.reshape(-1), axes, tiles_per)
                 me = axis_linear_index(axes)
 
                 def mine(a):
                     return jax.lax.dynamic_slice_in_dim(a, me * b, b)
 
-                qidx, stile, ttile = emit_ragged_worklist(
-                    tbase, tcnt, mine(stq[0]), mine(stq[1]),
-                    worklist_len=WL)
-                # relabel worklist tiles into the gathered buffer: the
-                # plan rows are sorted (fill = last real tile id), so a
-                # binary search lands every real entry; worklist pads
-                # name tile 0, whose probe row is trash-routed anyway
-                uniq_me = jax.lax.dynamic_index_in_dim(
-                    uniq, me, axis=0, keepdims=False)
-                sloc = jnp.searchsorted(uniq_me, stile).astype(jnp.int32)
-                tloc = jnp.searchsorted(uniq_me, ttile).astype(jnp.int32)
-                args = (gh, gd, gw, lo[uniq_me], hi[uniq_me], qidx,
-                        sloc, tloc)
-                if profile:
-                    op = (kops.wcsd_profile_ragged_compressed if compressed
-                          else kops.wcsd_profile_ragged)
-                    out = op(*args, num_rows=b + 1, num_levels=W,
-                             interpret=interpret, use_kernel=use_pallas)
-                else:
-                    wq = jnp.concatenate([
-                        mine(stq[2]), jnp.full((1,), 1 << 20, jnp.int32)])
-                    op = (kops.wcsd_query_ragged_compressed if compressed
-                          else kops.wcsd_query_ragged)
-                    out = op(*args, wq,
-                             interpret=interpret, use_kernel=use_pallas)
-                return out[:b]
+                with jax.named_scope("wcsd.gather"):
+                    # one fused reduce-scatter routes each device's
+                    # host-planned DISTINCT tile list to it, in linear
+                    # device order — each tile crosses the interconnect
+                    # once
+                    gh, gd, gw = ragged_tile_gather(
+                        (hub, dist, wlev), uniq.reshape(-1), axes,
+                        tiles_per)
+                with jax.named_scope("wcsd.emit_worklist"):
+                    qidx, stile, ttile = emit_ragged_worklist(
+                        tbase, tcnt, mine(stq[0]), mine(stq[1]),
+                        worklist_len=WL)
+                    # relabel worklist tiles into the gathered buffer: the
+                    # plan rows are sorted (fill = last real tile id), so
+                    # a binary search lands every real entry; worklist
+                    # pads name tile 0, whose probe row is trash-routed
+                    uniq_me = jax.lax.dynamic_index_in_dim(
+                        uniq, me, axis=0, keepdims=False)
+                    sloc = jnp.searchsorted(uniq_me, stile).astype(jnp.int32)
+                    tloc = jnp.searchsorted(uniq_me, ttile).astype(jnp.int32)
+                with jax.named_scope("wcsd.join"):
+                    args = (gh, gd, gw, lo[uniq_me], hi[uniq_me], qidx,
+                            sloc, tloc)
+                    if profile:
+                        op = (kops.wcsd_profile_ragged_compressed
+                              if compressed else kops.wcsd_profile_ragged)
+                        out = op(*args, num_rows=b + 1, num_levels=W,
+                                 interpret=interpret, use_kernel=use_pallas)
+                    else:
+                        wq = jnp.concatenate([
+                            mine(stq[2]),
+                            jnp.full((1,), 1 << 20, jnp.int32)])
+                        op = (kops.wcsd_query_ragged_compressed
+                              if compressed else kops.wcsd_query_ragged)
+                        out = op(*args, wq, interpret=interpret,
+                                 use_kernel=use_pallas)
+                with jax.named_scope("wcsd.unstage"):
+                    return out[:b]
 
             in_specs = (P(self.batch_axes, None),) * 3 + (P(None),) * 4 \
                 + (P(None, None), P(None, None))
@@ -1256,15 +1319,18 @@ class ShardedQueryEngine(_QueryEngineBase):
         return PendingResult(lambda: np.asarray(res)[:n], deps=(res,))
 
     def _profile_ragged_async(self, s, t) -> PendingResult:
+        t0 = tracing.now() if self.tracer.on else 0
         n = len(s)
         stq = self._stage_ragged(s, t)
         if self.mode == "sharded_labels":
             stq, perm = self._balance_ragged(stq)
             wl_len = self._balanced_worklist_len(stq)
             uniq, G = self._gather_plan(stq, wl_len)
+            t1, new = self._planned(t0, (True, stq.shape[1], wl_len, G))
             fn = self._ragged_fn(wl_len, profile=True, gather_cap=G)
             res = fn(*self._arena, self._put_staged(stq),
                      self._put_staged(uniq))
+            self._launched(t1, new)
 
             def finalize():
                 r = np.asarray(res)
@@ -1273,8 +1339,11 @@ class ShardedQueryEngine(_QueryEngineBase):
                 return out[:n]
 
             return PendingResult(finalize, deps=(res,))
-        fn = self._ragged_fn(self._shard_worklist_len(stq), profile=True)
+        wl_len = self._shard_worklist_len(stq)
+        t1, new = self._planned(t0, (True, stq.shape[1], wl_len, None))
+        fn = self._ragged_fn(wl_len, profile=True)
         res = fn(*self._arena, self._put_staged(stq))
+        self._launched(t1, new)
         return PendingResult(lambda: np.asarray(res)[:n], deps=(res,))
 
     def _dispatch_padded_profile(self, s, t):

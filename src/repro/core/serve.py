@@ -24,13 +24,14 @@ Production shape:
   * continuous batching — with ``max_wait_us`` set, a flush no longer
     waits for ``max_batch``: once ``min_batch`` requests are queued, the
     batch dispatches as soon as the in-flight slot is free (or its device
-    work is done — `PendingResult.ready` probes without blocking), and a
-    trickle that never fills ``min_batch``-sized bursts is bounded by the
-    ``max_wait_us`` deadline on the OLDEST queued request (checked on
-    every submit and on `poll`). Per-request enqueue→deliver latency is
-    recorded (`latency_summary` reports p50/p99 µs) and host flush time
-    is split into dispatch vs drain-wait (`ServeStats`), so SLO math sees
-    launch overhead and device wait separately.
+    work is done — `PendingResult.ready` probes without blocking), or
+    once the OLDEST queued request has waited ``max_wait_us`` (checked on
+    every submit and on `poll`). Below ``min_batch`` nothing fires.
+    Host flush time is split into dispatch, drain wait and delivery
+    (`ServeStats`), so SLO math sees launch overhead and device wait
+    separately; the tracer (`tracer.start()`, core/tracing.py) adds
+    per-request stamps and per-flush spans, from which
+    `latency_summary` reports p50/p99 µs.
   * read-once results — `result(rid)` pops the delivered answer, so a
     long-running server's result dict stays bounded by what is queued or
     in flight instead of growing one entry per request forever. Callers
@@ -50,6 +51,7 @@ from typing import Optional
 
 import numpy as np
 
+from . import tracing
 from .query import DeviceQueryEngine, PendingResult, ShardedQueryEngine
 from .resilience import (FlushRetryExhausted, RetryPolicy,
                          UnknownRequestError, WALReplayError,
@@ -64,11 +66,19 @@ class ServeStats:
     profile_requests: int = 0
     batches: int = 0
     memo_hits: int = 0
-    dispatch_time_s: float = 0.0  # host time launching batches (flush_async)
-    drain_wait_s: float = 0.0     # host time blocked on device results
+    # host time per flush, from the tracer's span stamps: staging, plan
+    # and launch (flush.stage); blocked on device results (drain.wait);
+    # handing answers out and filling the memos (drain.deliver)
+    dispatch_time_s: float = 0.0
+    drain_wait_s: float = 0.0
+    deliver_time_s: float = 0.0
+    delivered: int = 0            # answers drains handed out (riders too)
     max_batch: int = 0
-    deadline_flushes: int = 0     # flushes fired by the max_wait_us deadline
+    cap_flushes: int = 0          # flushes fired by max_batch
     opportunistic_flushes: int = 0  # flushes fired by a free in-flight slot
+    deadline_flushes: int = 0     # flushes fired by the max_wait_us deadline
+    sync_flushes: int = 0         # flushes of flush()/result()/flush_async()
+    new_programs: int = 0         # ragged flush shapes an engine ran first
     # flush watchdog (docs/resilience.md): per-cause retry counters
     timeout_retries: int = 0      # handle missed its deadline, re-dispatched
     error_retries: int = 0        # dispatch/wait raised, re-dispatched
@@ -76,14 +86,6 @@ class ServeStats:
     demotions: int = 0            # fallback-ladder steps down
     promotions: int = 0           # healthy probe windows stepping back up
     wal_appends: int = 0          # update batches logged to the WAL
-
-    @property
-    def flush_time_s(self) -> float:
-        # the pre-split lump (launch + drain), kept for bench-schema
-        # compatibility; SLO math should use the two components — drain
-        # wait is device time the host merely observes, dispatch time is
-        # host overhead a faster frontend could shrink
-        return self.dispatch_time_s + self.drain_wait_s
 
 
 class WCSDServer:
@@ -141,6 +143,8 @@ class WCSDServer:
         # wraps every engine the server builds (chaos fault injection —
         # checkpoint/fault.py `FaultyEngine`); it survives rebuilds.
         self.index = None
+        self.stats = ServeStats()
+        self.tracer = tracing.Tracer()    # off until tracer.start()
         self.compact_threshold = compact_threshold
         self._compact_kwargs = dict(compact_kwargs or {})
         self.retry_policy = RetryPolicy(
@@ -231,18 +235,17 @@ class WCSDServer:
         self._inflight_dispatch = None
         self._inflight_prof_batch: list | None = None
         self._inflight_prof_dispatch = None
-        # enqueue→deliver latency: stamped per rid at submit, recorded
-        # (µs) the moment the answer lands in the result dict
-        self._enqueue_t: dict[int, float] = {}
-        self.latencies_us: list[float] = []
         self._pending_since: float | None = None  # oldest queued enqueue
-        self.stats = ServeStats()
+        self._flush_seq = 0      # id of the next flush
+        self._inflight_fid = tracing.PENDING  # flush holding the slot
 
     # ------------------------------------------------------------- dynamic
     def _make_engine(self):
         cfg = (self._ladder[self.mode_index][1]
                if self._ladder is not None else self._engine_config)
         eng = self._build_engine(cfg)
+        eng.tracer = self.tracer
+        eng.serve_stats = self.stats
         if self._engine_wrapper is not None:
             eng = self._engine_wrapper(eng)
         return eng
@@ -471,28 +474,23 @@ class WCSDServer:
         return (s, t)
 
     # ------------------------------------------------------------- requests
-    def _deliver(self, rid: int) -> None:
-        """Record the enqueue→deliver latency of a rid whose answer just
-        landed in the result dict."""
-        t0 = self._enqueue_t.pop(rid, None)
-        if t0 is not None:
-            self.latencies_us.append((time.perf_counter() - t0) * 1e6)
-
     def submit(self, s: int, t: int, w_level: int) -> int:
         """Queue one request; returns a request id."""
+        tr = self.tracer
+        t_enq = tracing.now() if tr.on else 0
         rid = self._next_rid
         self._next_rid += 1
         key = self._memo_key(s, t, w_level)
         pkey = self._profile_key(s, t)
         self.stats.requests += 1
-        self._enqueue_t[rid] = time.perf_counter()
+        fid, rides = tracing.PENDING, False
         if key in self.memo:
             self.memo.move_to_end(key)
             self.results[rid] = self.memo[key]
             self.result_versions[rid] = self.graph_version
             self.result_modes[rid] = "memo"
             self.stats.memo_hits += 1
-            self._deliver(rid)
+            fid = tracing.MEMO
         elif (pkey in self.profile_memo
               and 0 <= w_level <= getattr(self.engine, "num_levels", -1)):
             # a cached profile answers EVERY level of its pair: read the
@@ -504,7 +502,7 @@ class WCSDServer:
             self.result_modes[rid] = "memo"
             self._memo_put(key, self.results[rid])
             self.stats.memo_hits += 1
-            self._deliver(rid)
+            fid = tracing.MEMO
         elif key in self._inflight_pos:
             # the answer is already being computed in the in-flight batch:
             # piggyback on it instead of re-queueing the hot key (counted
@@ -512,19 +510,26 @@ class WCSDServer:
             self._inflight_extra.append((rid, self._inflight_pos[key]))
             self._inflight_rids.add(rid)
             self.stats.memo_hits += 1
+            fid, rides = self._inflight_fid, True
         elif key in self._pending_pos:
             # already queued but not yet dispatched: ride the queued
             # request's batch slot instead of occupying a second one
             self._pending_extra.append((rid, self._pending_pos[key]))
             self._pending_rids.add(rid)
             self.stats.memo_hits += 1
+            rides = True
         else:
             if not self.pending and not self.pending_profiles:
                 self._pending_since = time.perf_counter()
             self._pending_pos[key] = len(self.pending)
             self.pending.append((rid, s, t, w_level))
             self._pending_rids.add(rid)
+            if tr.on:
+                tr.enqueue(rid, t_enq, fid)
             self._maybe_flush()
+            return rid
+        if tr.on:
+            tr.enqueue(rid, t_enq, fid, rides)
         return rid
 
     def submit_profile(self, s: int, t: int) -> int:
@@ -532,35 +537,44 @@ class WCSDServer:
         for every level 0..num_levels, answered by ONE label sweep (see
         `DeviceQueryEngine.query_profile`). Returns a request id for
         `profile_result`."""
+        tr = self.tracer
+        t_enq = tracing.now() if tr.on else 0
         rid = self._next_rid
         self._next_rid += 1
         key = self._profile_key(s, t)
         self.stats.profile_requests += 1
-        self._enqueue_t[rid] = time.perf_counter()
+        fid, rides = tracing.PENDING, False
         if key in self.profile_memo:
             self.profile_memo.move_to_end(key)
             self.profile_results[rid] = self.profile_memo[key].copy()
             self.profile_result_versions[rid] = self.graph_version
             self.profile_result_modes[rid] = "memo"
             self.stats.memo_hits += 1
-            self._deliver(rid)
+            fid = tracing.MEMO
         elif key in self._inflight_prof_pos:
             self._inflight_prof_extra.append(
                 (rid, self._inflight_prof_pos[key]))
             self._inflight_prof_rids.add(rid)
             self.stats.memo_hits += 1
+            fid, rides = self._inflight_fid, True
         elif key in self._pending_prof_pos:
             self._pending_prof_extra.append(
                 (rid, self._pending_prof_pos[key]))
             self._pending_prof_rids.add(rid)
             self.stats.memo_hits += 1
+            rides = True
         else:
             if not self.pending and not self.pending_profiles:
                 self._pending_since = time.perf_counter()
             self._pending_prof_pos[key] = len(self.pending_profiles)
             self.pending_profiles.append((rid, s, t))
             self._pending_prof_rids.add(rid)
+            if tr.on:
+                tr.enqueue(rid, t_enq, fid)
             self._maybe_flush()
+            return rid
+        if tr.on:
+            tr.enqueue(rid, t_enq, fid, rides)
         return rid
 
     def _slot_done(self) -> bool:
@@ -585,19 +599,20 @@ class WCSDServer:
         if npend >= self.max_batch:
             # async: dispatch only — the device chews on this batch
             # while the host accepts and plans the next one
-            self.flush_async()
+            self.stats.cap_flushes += 1
+            self.flush_async("cap")
             return
         if self.max_wait_us is None or npend < self.min_batch:
             return
         if self._inflight is None and self._inflight_prof is None \
                 or self._slot_done():
             self.stats.opportunistic_flushes += 1
-            self.flush_async()
+            self.flush_async("opportunistic")
         elif (self._pending_since is not None
               and (time.perf_counter() - self._pending_since) * 1e6
               >= self.max_wait_us):
             self.stats.deadline_flushes += 1
-            self.flush_async()
+            self.flush_async("deadline")
 
     def poll(self) -> None:
         """Deadline tick for continuous batching: harvest the in-flight
@@ -619,13 +634,15 @@ class WCSDServer:
         self._maybe_flush()
 
     def latency_summary(self) -> dict:
-        """p50/p99 (µs) of enqueue→deliver latency over every delivered
-        request so far (memo hits included — they deliver at enqueue).
-        Before anything has completed the percentiles are zeros with
-        ``n == count == 0`` — never an exception."""
-        if not self.latencies_us:
+        """p50/p99 (µs) of enqueue→deliver latency over every request the
+        tracer recorded and saw delivered (memo hits included — they
+        deliver at enqueue). With nothing delivered, or the tracer never
+        on, the percentiles are zeros with ``n == count == 0`` — never an
+        exception."""
+        req = tracing.request_times(self.tracer.snapshot())
+        if not len(req["rid"]):
             return {"count": 0, "n": 0, "p50_us": 0.0, "p99_us": 0.0}
-        arr = np.asarray(self.latencies_us)
+        arr = (req["deliver_ns"] - req["enqueue_ns"]) * 1e-3
         return {"count": int(arr.size), "n": int(arr.size),
                 "p50_us": float(np.percentile(arr, 50)),
                 "p99_us": float(np.percentile(arr, 99))}
@@ -635,7 +652,7 @@ class WCSDServer:
         if len(self.memo) > self.memo_capacity:
             self.memo.popitem(last=False)
 
-    def flush_async(self) -> None:
+    def flush_async(self, cause: str = "sync") -> None:
         """Dispatch the pending batch without waiting for its results.
 
         Double-buffered: at most one batch is in flight, so dispatching
@@ -652,11 +669,23 @@ class WCSDServer:
         propagate, with every queued request still pending — a later
         flush retries the same batch and `result(rid)` still
         blocks-and-answers instead of failing forever.
+
+        ``cause`` names the trigger for the tracer: "cap", "opportunistic"
+        and "deadline" come from the admission checks, "sync" (the
+        default) from `flush`, `result` and direct calls.
         """
         if not self.pending and not self.pending_profiles:
             return
         self._drain()
-        t0 = time.perf_counter()
+        t0 = tracing.now()
+        if cause == "sync":
+            self.stats.sync_flushes += 1
+        fid = self._inflight_fid = self._flush_seq
+        self._flush_seq += 1
+        tr = self.tracer
+        if tr.on:
+            tr.open_flush(fid, cause, len(self.pending)
+                          + len(self.pending_profiles))
         # pad to the next power of two (bounded recompiles); the csr engine
         # pads each planned sub-batch itself, and the sharded engine pads to
         # its own device multiple, so padding here would only add dummy
@@ -686,6 +715,9 @@ class WCSDServer:
 
             # dispatch BEFORE the queue is cleared (see docstring)
             handle = self._dispatch_with_retry(dispatch)
+            if tr.on:
+                tr.carry([b[0] for b in batch]
+                         + [r for r, _ in self._pending_extra], fid)
             keys = [self._memo_key(b[1], b[2], b[3]) for b in batch]
             self._inflight = (handle, [b[0] for b in batch], keys)
             self._inflight_batch = batch
@@ -717,6 +749,9 @@ class WCSDServer:
                 return PendingResult(lambda: res)
 
             handle = self._dispatch_with_retry(prof_dispatch)
+            if tr.on:
+                tr.carry([b[0] for b in batch]
+                         + [r for r, _ in self._pending_prof_extra], fid)
             keys = [self._profile_key(b[1], b[2]) for b in batch]
             self._inflight_prof = (handle, [b[0] for b in batch], keys)
             self._inflight_prof_batch = batch
@@ -733,7 +768,10 @@ class WCSDServer:
             self.stats.max_batch = max(self.stats.max_batch, n)
         self._pending_since = None
         self.stats.batches += 1
-        self.stats.dispatch_time_s += time.perf_counter() - t0
+        t1 = tracing.now()
+        self.stats.dispatch_time_s += (t1 - t0) * 1e-9
+        if tr.on:
+            tr.span(tracing.STAGE, t0, t1, fid)
 
     def _requeue_scalar(self, batch, extra) -> None:
         """Put a terminally-failed in-flight batch back at the FRONT of
@@ -782,8 +820,9 @@ class WCSDServer:
             return
         if self._inflight is None and self._inflight_prof is None:
             return
-        t0 = time.perf_counter()
         ver = self.graph_version
+        fid = self._inflight_fid
+        self.tracer.cur = fid     # a re-dispatch's engine spans
         self._retrying = True
         try:
             if self._inflight is not None:
@@ -797,6 +836,7 @@ class WCSDServer:
                 self._inflight_extra = []
                 self._inflight_batch = None
                 self._inflight_dispatch = None
+                t0 = tracing.now()
                 try:
                     out = self._await_handle(
                         handle,
@@ -804,6 +844,7 @@ class WCSDServer:
                 except Exception:
                     self._requeue_scalar(batch, extra)
                     raise
+                t1 = tracing.now()
                 out = out[:len(rids)]
                 mode = self.mode
                 for rid, key, d in zip(rids, keys, out):
@@ -811,12 +852,11 @@ class WCSDServer:
                     self.result_versions[rid] = ver
                     self.result_modes[rid] = mode
                     self._memo_put(key, int(d))
-                    self._deliver(rid)
                 for rid, pos in extra:  # duplicates submitted in flight
                     self.results[rid] = int(out[pos])
                     self.result_versions[rid] = ver
                     self.result_modes[rid] = mode
-                    self._deliver(rid)
+                self._drained(fid, t0, t1, len(rids) + len(extra))
             if self._inflight_prof is not None:
                 handle, rids, keys = self._inflight_prof
                 extra = self._inflight_prof_extra
@@ -828,6 +868,7 @@ class WCSDServer:
                 self._inflight_prof_extra = []
                 self._inflight_prof_batch = None
                 self._inflight_prof_dispatch = None
+                t0 = tracing.now()
                 try:
                     out = self._await_handle(
                         handle,
@@ -835,6 +876,7 @@ class WCSDServer:
                 except Exception:
                     self._requeue_profile(batch, extra)
                     raise
+                t1 = tracing.now()
                 out = np.asarray(out)[:len(rids)]
                 mode = self.mode
                 for rid, key, prof in zip(rids, keys, out):
@@ -849,16 +891,14 @@ class WCSDServer:
                     self.profile_memo[key] = arr
                     if len(self.profile_memo) > self.memo_capacity:
                         self.profile_memo.popitem(last=False)
-                    self._deliver(rid)
                 for rid, pos in extra:
                     self.profile_results[rid] = np.array(out[pos],
                                                          dtype=np.int32)
                     self.profile_result_versions[rid] = ver
                     self.profile_result_modes[rid] = mode
-                    self._deliver(rid)
+                self._drained(fid, t0, t1, len(rids) + len(extra))
         finally:
             self._retrying = False
-        self.stats.drain_wait_s += time.perf_counter() - t0
         # health accounting: a drain that completed with no new retry
         # events is a healthy flush; probe_interval of them in a row
         # re-promotes a degraded server one rung up the ladder
@@ -875,6 +915,19 @@ class WCSDServer:
             self.stats.promotions += 1
             self._healthy = 0
             self.engine = self._make_engine()
+
+    def _drained(self, fid: int, t0: int, t1: int, n: int) -> None:
+        """Account one drained batch: waited from t0 to t1, then handed
+        ``n`` answers out until now."""
+        t2 = tracing.now()
+        st = self.stats
+        st.drain_wait_s += (t1 - t0) * 1e-9
+        st.deliver_time_s += (t2 - t1) * 1e-9
+        st.delivered += n
+        tr = self.tracer
+        if tr.on:
+            tr.span(tracing.WAIT, t0, t1, fid)
+            tr.span(tracing.DELIVER, t1, t2, fid)
 
     def flush(self) -> None:
         """Synchronous flush: dispatch anything pending and drain."""

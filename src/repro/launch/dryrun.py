@@ -177,6 +177,7 @@ def run_serve(quick: bool) -> None:
         srv_cb = WCSDServer(idx, mesh=make_serving_mesh(),
                             **{**cfg.server_kwargs(), "max_batch": 64,
                                "max_wait_us": 200.0, "min_batch": 4})
+        srv_cb.tracer.start()
         rids = [srv_cb.submit(int(a), int(b), int(c))
                 for a, b, c in zip(s, t, wl)]
         srv_cb.flush()

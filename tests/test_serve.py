@@ -501,20 +501,22 @@ def test_profile_dispatch_failure_keeps_profiles(small_index, serve_layout):
 
 # -------------------------------------------------------- latency stats
 def test_flush_time_split_and_latency(small_index, serve_layout):
-    """flush_time_s is the sum of its two new components (dispatch vs
-    drain wait), and every request gets an enqueue->deliver latency
+    """Host flush time splits into dispatch, drain wait and delivery, and
+    with the tracer on every request gets an enqueue->deliver latency
     sample — memo hits included."""
     srv = WCSDServer(small_index, max_batch=16, layout=serve_layout)
+    srv.tracer.start()
     s, t, wl = random_queries_for(small_index, 64, seed=12)
     srv.query_many(s, t, wl)
     st = srv.stats
     assert st.dispatch_time_s > 0.0 and st.drain_wait_s > 0.0
-    assert st.flush_time_s == pytest.approx(st.dispatch_time_s
-                                            + st.drain_wait_s)
+    assert st.deliver_time_s > 0.0
+    assert st.delivered == st.requests - st.memo_hits + (
+        srv.tracer.snapshot()["requests"]["rides"].sum())
     lat = srv.latency_summary()
     assert lat["count"] == 64               # all delivered -> all sampled
     assert lat["p99_us"] >= lat["p50_us"] >= 0.0
-    assert not srv._enqueue_t               # no stamp leaks
+    assert srv.tracer.snapshot()["dropped"] == 0
 
 
 # ---------------------------------------------------- continuous batching
@@ -653,6 +655,7 @@ def test_continuous_traffic_differential(mode):
         srv = WCSDServer(idx, graph=g, compact_threshold=None, **kw)
     else:
         srv = WCSDServer(idx, **kw)
+    srv.tracer.start()
 
     rng = np.random.default_rng(77)
     grid = constrained_distance_grid(g)
