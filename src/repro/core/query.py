@@ -293,7 +293,11 @@ class PendingResult:
     device execution of batch k. ``deps`` are the in-flight device arrays
     the finalizer will read: `ready()` probes them without blocking, which
     is what lets the server dispatch opportunistically the moment the
-    in-flight slot's device work finishes.
+    in-flight slot's device work finishes. Construction also starts each
+    dep's device-to-host copy (`jax.Array.copy_to_host_async`), so the
+    runtime moves the answers as soon as the program defines them instead
+    of when `wait()` asks for them; deps without the method (readiness
+    probes, host values) are left alone.
 
     ``deadline`` (absolute `time.monotonic()` seconds, or None) is stamped
     by the flush watchdog at dispatch: a handle past its deadline that is
@@ -306,6 +310,10 @@ class PendingResult:
         self._deps = tuple(deps)
         self._out = None
         self.deadline = None
+        for d in self._deps:
+            start_copy = getattr(d, "copy_to_host_async", None)
+            if start_copy is not None:
+                start_copy()
 
     def expired(self, now: float) -> bool:
         """True when a deadline is set, has passed, and the handle still

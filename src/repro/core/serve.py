@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import os
 import time
 from typing import Optional
 
@@ -58,6 +59,18 @@ from .resilience import (FlushRetryExhausted, RetryPolicy,
                          build_fallback_ladder)
 from .wc_index import (DynamicWCIndex, PackedWCIndex, WCIndex,
                        round_to_pow2)
+
+# A drain under the watchdog probes its handle's readiness for up to
+# SPIN_S with only a yield of the CPU and the GIL between probes: a
+# flush's answers land within milliseconds, and a sleep between probes
+# would add the OS's wake-up lag to every drain while the device sits
+# idle. The yield matters too: on a TPU v5e a probe loop that keeps the
+# GIL slows the runtime's own threads, so the next launch and the
+# program itself take longer. A wait that outlasts SPIN_S is no ordinary
+# flush (a wedged handle, a huge batch), so it then sleeps POLL_SLEEP_S
+# between probes until the answers land or the deadline passes.
+SPIN_S = 0.01
+POLL_SLEEP_S = 1e-4
 
 
 @dataclasses.dataclass
@@ -72,6 +85,10 @@ class ServeStats:
     dispatch_time_s: float = 0.0
     drain_wait_s: float = 0.0
     deliver_time_s: float = 0.0
+    # the part of drain_wait_s inside handle.wait() once ready() said the
+    # device work was done (without a watchdog deadline, all of wait()):
+    # the answers' device-to-host copy and materialisation
+    readback_time_s: float = 0.0
     delivered: int = 0            # answers drains handed out (riders too)
     max_batch: int = 0
     cap_flushes: int = 0          # flushes fired by max_batch
@@ -341,23 +358,35 @@ class WCSDServer:
         batch re-dispatched via ``redispatch``; a raising wait() retries
         the same way. Exhaustion demotes one rung and resets the budget;
         at the bottom it raises `FlushRetryExhausted` (the caller
-        re-queues the batch — nothing is dropped)."""
+        re-queues the batch — nothing is dropped). With a deadline armed,
+        readiness is probed with a yield between probes (see SPIN_S) and
+        the deadline checked on every probe; the time inside a successful
+        `wait()` is counted in ``readback_time_s``."""
         p = self.retry_policy
         attempt = 0
         while True:
             timed_out, err = False, None
             deadline = getattr(handle, "deadline", None)
             if deadline is not None:
+                spin_until = time.monotonic() + SPIN_S
                 while not handle.ready():
-                    if time.monotonic() > deadline:
+                    now = time.monotonic()
+                    if now > deadline:
                         timed_out = True
                         break
-                    time.sleep(1e-4)
+                    if now > spin_until:
+                        time.sleep(POLL_SLEEP_S)
+                    else:
+                        os.sched_yield()
             if not timed_out:
+                t0 = tracing.now()
                 try:
-                    return handle.wait()
+                    out = handle.wait()
                 except Exception as e:
                     err = e
+                else:
+                    self.stats.readback_time_s += (tracing.now() - t0) * 1e-9
+                    return out
             attempt += 1
             if attempt > p.max_retries:
                 self.stats.exhausted += 1

@@ -709,3 +709,155 @@ def test_continuous_traffic_differential(mode):
     if submitted:
         s, t, wl = (np.array(x, np.int32) for x in zip(*submitted))
         assert np.array_equal(srv.query_many(s, t, wl), grid[s, t, wl])
+
+
+# ------------------------------------------------------------- readback
+class _CopyProbe:
+    """A device-array stand-in that records `copy_to_host_async` calls and
+    reports ready after ``ready_after`` `is_ready` probes."""
+
+    def __init__(self, ready_after=0):
+        self.copies = 0
+        self.probes = 0
+        self.ready_after = ready_after
+
+    def copy_to_host_async(self):
+        self.copies += 1
+
+    def is_ready(self):
+        self.probes += 1
+        return self.probes > self.ready_after
+
+
+def test_pending_result_starts_one_host_copy_per_dep():
+    """Construction starts each dep's device-to-host copy exactly once;
+    ready() and wait() do not start another."""
+    from repro.core.query import PendingResult
+    deps = [_CopyProbe(), _CopyProbe()]
+    h = PendingResult(lambda: np.arange(3, dtype=np.int32), deps=deps)
+    assert [d.copies for d in deps] == [1, 1]
+    assert h.ready()
+    assert np.array_equal(h.wait(), np.arange(3))
+    assert [d.copies for d in deps] == [1, 1]
+
+
+def test_pending_result_deps_without_copy_method():
+    """Readiness probes without `copy_to_host_async` (the `_Gate` test
+    deps, host values) are left alone and still gate ready()."""
+    from repro.core.query import PendingResult
+    gate, probe = _Gate(), _CopyProbe()
+    h = PendingResult(lambda: np.int32(7), deps=(gate, probe, 3))
+    assert probe.copies == 1
+    assert not h.ready()
+    gate.ready = True
+    assert h.ready() and h.wait() == 7
+
+
+def _sharded_labels_engine(idx):
+    from repro.core.query import ShardedQueryEngine
+    from repro.launch.mesh import make_serving_mesh
+    eng = ShardedQueryEngine(idx, mesh=make_serving_mesh(), layout="csr",
+                             device_budget_bytes=1)
+    assert eng.mode == "sharded_labels"
+    return eng
+
+
+@pytest.mark.parametrize("path", ["ragged", "bucket_pair", "sharded_labels"])
+def test_handle_wait_is_bit_identical(small_index, path):
+    """With the copy started at dispatch, wait() still returns exactly
+    np.asarray of the handle's device arrays, through its finalizer (the
+    bucket-pair assembly, the row-sharded unpermute): the oracle's answers,
+    same dtype, bit for bit."""
+    from repro.core.query import DeviceQueryEngine
+    if path == "sharded_labels":
+        eng = _sharded_labels_engine(small_index)
+    else:
+        eng = DeviceQueryEngine(small_index, layout="csr", dispatch=path)
+    s, t, wl = random_queries_for(small_index, 100, seed=31)
+    h = eng.query_async(s, t, wl)
+    raw = [np.asarray(d).copy() for d in h._deps]
+    got = h.wait()
+    exp = np.asarray(small_index.query_batch(s, t, wl))
+    assert got.dtype == np.int32 and got.tobytes() == exp.astype(
+        np.int32).tobytes()
+    if path == "ragged":
+        assert got.tobytes() == raw[0][:len(s)].tobytes()
+    assert all(np.array_equal(np.asarray(d), r)
+               for d, r in zip(h._deps, raw))
+
+
+@pytest.mark.parametrize("timeout_ms", [None, 5000.0])
+def test_readback_counters(small_index, serve_layout, timeout_ms):
+    """Every flush is drained through one handle, and the readback is the
+    part of the drain's wait after readiness: 0 < readback <= drain wait."""
+    srv = WCSDServer(small_index, max_batch=16, layout=serve_layout,
+                     flush_timeout_ms=timeout_ms)
+    handles = []
+    inner = srv.engine.query_async
+
+    def counted(s, t, w):
+        handles.append(inner(s, t, w))
+        return handles[-1]
+
+    srv.engine.query_async = counted
+    s, t, wl = random_queries_for(small_index, 64, seed=13)
+    srv.query_many(s, t, wl)
+    st = srv.stats
+    assert len(handles) >= 2 and st.batches == len(handles)
+    assert 0.0 < st.readback_time_s <= st.drain_wait_s
+
+
+def test_ready_handle_drained_without_sleep(small_index, serve_layout,
+                                            monkeypatch):
+    """Under the watchdog, a handle that turns ready after a few probes is
+    drained with only a yield between probes: no sleep."""
+    import repro.core.serve as serve_mod
+    from repro.core.query import PendingResult
+    srv = WCSDServer(small_index, max_batch=64, layout=serve_layout,
+                     flush_timeout_ms=5000.0)
+    probe = _CopyProbe(ready_after=25)
+    inner = srv.engine.query_async
+    srv.engine.query_async = lambda s, t, w: PendingResult(
+        inner(s, t, w).wait, deps=(probe,))
+    sleeps, yields = [], []
+    monkeypatch.setattr(serve_mod.time, "sleep", sleeps.append)
+    monkeypatch.setattr(serve_mod.os, "sched_yield",
+                        lambda: yields.append(1))
+    rid = srv.submit(3, 9, 1)
+    srv.flush()
+    assert probe.probes > 25 and sleeps == [] and len(yields) >= 25
+    assert srv.stats.batches == 1 and srv.stats.timeout_retries == 0
+    assert 0.0 < srv.stats.readback_time_s <= srv.stats.drain_wait_s
+    assert srv.result(rid) == small_index.query_batch(
+        np.array([3]), np.array([9]), np.array([1]))[0]
+
+
+def test_wedged_handle_backs_off_then_times_out(small_index, serve_layout,
+                                                monkeypatch):
+    """A handle still not ready after SPIN_S of probing is polled every
+    POLL_SLEEP_S, and the watchdog abandons it at its deadline exactly as
+    before: one timeout retry, the re-dispatched batch answers."""
+    import repro.core.serve as serve_mod
+    from repro.core.query import PendingResult
+    srv = WCSDServer(small_index, max_batch=64, layout=serve_layout,
+                     flush_timeout_ms=40.0, backoff_base_ms=0.0, jitter=0.0)
+    gate, n = _Gate(), {"calls": 0}
+    inner = srv.engine.query_async
+
+    def dispatch(s, t, w):
+        n["calls"] += 1
+        h = inner(s, t, w)
+        return PendingResult(h.wait, deps=(gate,)) if n["calls"] == 1 else h
+
+    srv.engine.query_async = dispatch
+    sleeps = []
+    real_sleep = serve_mod.time.sleep
+    monkeypatch.setattr(serve_mod.time, "sleep",
+                        lambda sec: (sleeps.append(sec), real_sleep(sec)))
+    rid = srv.submit(3, 9, 1)
+    srv.flush()
+    assert n["calls"] == 2 and srv.stats.timeout_retries == 1
+    assert serve_mod.POLL_SLEEP_S in sleeps
+    assert 0.0 < srv.stats.readback_time_s <= srv.stats.drain_wait_s
+    assert srv.result(rid) == small_index.query_batch(
+        np.array([3]), np.array([9]), np.array([1]))[0]
