@@ -6,10 +6,12 @@ run the harness's own check (`check.judge`) over what it answered.
     python bench/control.py --workload road-uniform --seeds 1,2,3
 
 Every request of the window is answered by the control, stamped as a
-device answer delivered on time, and judged with the run's own sample.
-Prints one JSON line per seed: ``correct`` (which has to read false) and
-each check's number. The benchmark's own runs never run this; the reading
-sets the upper end of the ``wrong`` check's limit (PERF.md).
+device answer delivered on time, and judged with the run's own sample: a
+point request with the loosened reference at its level, a profile request
+with it at every level 0..W. Prints one JSON line per seed: ``correct``
+(which has to read false) and each check's number. The benchmark's own
+runs never run this; the reading sets the upper end of the ``wrong`` and
+``profile_wrong`` checks' limits (PERF.md).
 """
 from __future__ import annotations
 
@@ -30,19 +32,31 @@ def control_run(cell, seed: int, seconds: float) -> dict:
     from harness.check import duplicates, judge
     from harness.drive import Requests
     from harness.reference import Reference
-    from harness.traffic import PairSource, open_schedule, rng_for
+    from harness.traffic import POINT, PairSource, open_schedule, rng_for
 
     ref = Reference(graphs.make_graph(cell.config))
     rng = rng_for(seed, "window")
     due = open_schedule(cell.mix, seconds, rng)
-    req = Requests.empty(*PairSource(cell.mix, ref.V, ref.num_levels,
-                                     seed).draw(rng, len(due)))
-    n = req.n = len(due)
+    n = len(due)
+    src = PairSource(cell.mix, ref.V, ref.num_levels, seed)
+    req = Requests.empty(*src.draw(rng, n),
+                         src.kinds(rng_for(seed, "kind", "window"), n),
+                         ref.num_levels)
+    req.n = n
     req.due = req.submit = due
     req.deliver = due + 1e-3
-    req.answer = ref.control(req.s, req.t, req.w)
+    point = req.kind == POINT
+    req.answer[point] = ref.control(req.s[point], req.t[point],
+                                    req.w[point])
+    if req.profile is not None:
+        levels = ref.num_levels + 1
+        req.profile[~point] = ref.control(
+            np.repeat(req.s[~point], levels),
+            np.repeat(req.t[~point], levels),
+            np.tile(np.arange(levels), int((~point).sum()))
+        ).reshape(-1, levels)
     dup = duplicates(req.s, req.t, req.w, req.submit, req.deliver, ref.V,
-                     ref.num_levels)
+                     ref.num_levels, req.kind)
     work = np.ones(n, np.int64)
     checks, counts = judge(req, n, ref, dup, work, seed,
                            {"mode": "primary", "retries": 0,

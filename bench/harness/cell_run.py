@@ -21,7 +21,7 @@ import numpy as np
 from . import drive, graphs, index_cache, trace
 from .check import duplicates, judge
 from .reference import Reference
-from .traffic import PairSource, open_schedule, rng_for
+from .traffic import PROFILE, PairSource, open_schedule, rng_for
 
 WARM_TRAFFIC_S = 4.0     # the cell's own traffic before the window
 WARM_SLICE_S = 1.0       # further slices while they still compile
@@ -52,6 +52,7 @@ class Run:
     peaks: dict | None
     trace: object | None      # trace.TraceSummary of [trace_from, seconds)
     trace_from: float
+    kind: np.ndarray | None = None   # POINT or PROFILE; None: all points
 
 
 def _stats(srv) -> dict:
@@ -63,14 +64,15 @@ def warm_up(srv, src, seed, mix, counter, row_entries) -> None:
     until a slice lowers nothing new (and the memo is at steady state)."""
     from repro.core.wc_index import LANE
     rng = rng_for(seed, "warmup")
+    kinds = rng_for(seed, "kind", "warmup")
     tile_cnt = np.maximum(-(-row_entries // LANE), 1)
-    sent = drive.warm_shapes(srv, src, rng, tile_cnt)
+    sent = drive.warm_shapes(srv, src, rng, tile_cnt, kinds)
     log(f"warm-up: {sent} requests over every flush shape, "
         f"{counter.lowered} programs lowered, {counter.compiled} compiled")
     slices = [WARM_TRAFFIC_S] + [WARM_SLICE_S] * WARM_SLICES_MAX
     for k, secs in enumerate(slices):
         before = counter.lowered
-        drive_mix(srv, mix, src, rng, secs, drive.HostClock())
+        drive_mix(srv, mix, src, rng, secs, drive.HostClock(), kinds=kinds)
         if k and counter.lowered == before:
             break
     log(f"warm-up: traffic {WARM_TRAFFIC_S + WARM_SLICE_S * k:.0f} s, "
@@ -79,11 +81,14 @@ def warm_up(srv, src, seed, mix, counter, row_entries) -> None:
 
 
 def drive_mix(srv, mix, src, rng, seconds, clock,
-              hook=(float("inf"), None)):
+              hook=(float("inf"), None), kinds=None):
     """Drive the mix for ``seconds``; returns the Requests and the time
-    the window opened. Requests are drawn before it opens."""
+    the window opened. Requests are drawn before it opens, their kinds
+    from ``kinds`` (the kind stream; a mix without profiles needs
+    none)."""
     offsets = open_schedule(mix, seconds, rng)
-    req = drive.Requests.empty(*src.draw(rng, len(offsets)))
+    n = len(offsets)
+    req = drive.Requests.empty(*src.draw(rng, n), src.kinds(kinds, n), src.W)
     start = drive.pc() + 0.005
     req.due = start + offsets
     drive.run_open(srv, req, start + seconds, clock, hook)
@@ -150,7 +155,8 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool, t_start,
     hook_t = (time.perf_counter() + max(seconds - TRACE_S, 0.0) if traced
               else float("inf"))
     req, open_at = drive_mix(srv, mix, src, rng, seconds, clock,
-                            (hook_t, start_trace))
+                            (hook_t, start_trace),
+                            kinds=rng_for(seed, "kind", "window"))
     setup_s = open_at - t_start
     close = open_at + seconds
     if traced:
@@ -168,6 +174,9 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool, t_start,
         f"{after['batches'] - before['batches']} flushes (largest "
         f"{after['max_batch']}); programs lowered in the window "
         f"{in_window['lowered']}, compiled {in_window['compiled']}")
+    if req.profile is not None:
+        log(f"window: {after['profile_requests'] - before['profile_requests']}"
+            " of the requests are profiles")
 
     summary = None
     if traced:
@@ -185,7 +194,7 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool, t_start,
     edges = graphs.make_graph(cfg)
     ref = Reference(edges)
     dup = duplicates(req.s[:n], req.t[:n], req.w[:n], req.submit[:n],
-                     req.deliver[:n], ref.V, ref.num_levels)
+                     req.deliver[:n], ref.V, ref.num_levels, req.kind[:n])
     work = (row_entries[req.s[:n]].astype(np.int64)
             * row_entries[req.t[:n]])
     t0 = time.perf_counter()
@@ -193,6 +202,11 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool, t_start,
     log(f"check: {sum(counts.values())} answers against the reference "
         f"({counts['device']} device, {counts['memo']} memo, "
         f"{counts['dup']} duplicate) in {time.perf_counter() - t0:.1f} s")
+    if "profile_device" in counts:
+        nprof = int(np.sum(req.kind[:n] == PROFILE))
+        log(f"check: of them staircases of {nprof} profile requests: "
+            f"{counts['profile_device']} device, {counts['profile_memo']} "
+            f"memo, {counts['profile_dup']} duplicate")
 
     peaks = None
     if platform == "tpu":
@@ -206,7 +220,8 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool, t_start,
               deliver=rel(req.deliver), memo=req.mode[:n] == 1, dup=dup,
               stats=delta, host_s=dict(clock.seconds), row_entries=row_entries,
               peaks=peaks, trace=summary,
-              trace_from=(marks["anchor"] - open_at) if traced else seconds)
+              trace_from=(marks["anchor"] - open_at) if traced else seconds,
+              kind=req.kind[:n])
     wanted = cell.per_layer if traced else cell.end_to_end
     metrics = {}
     for m in wanted:

@@ -9,7 +9,15 @@ schedule (an open loop), whatever the server does:
 - ``pairs``: ``"uniform"`` (s and t uniform over the vertices) or
   ``"zipf"`` (s and t each drawn Zipf(``zipf_a``) over a permutation of the
   vertices that the seed draws: the popular vertices differ per seed);
-- ``levels``: ``"uniform"``, w uniform over the graph's quality levels.
+- ``levels``: ``"uniform"``, w uniform over the graph's quality levels;
+- ``profile_share`` (0 when absent): the share of requests that are
+  profile requests, each asking for the whole staircase ``dist(s, t, w)``
+  at every level 0..W (`WCSDServer.submit_profile`). A profile request
+  keeps its drawn s and t; its w is not used. Exactly
+  ``round(share * n)`` of a draw's n requests are profiles, so every seed
+  offers the same work; which ones comes from a stream of its own
+  (``kind``), so s, t and w are drawn exactly as in a mix without
+  profiles.
 
 Everything comes from ``--seed`` through named streams, so the window's
 requests, the warm-up's and the correctness sample never share draws.
@@ -18,12 +26,20 @@ from __future__ import annotations
 
 import numpy as np
 
-STREAMS = {"window": 1, "warmup": 2, "sample": 3, "popularity": 4}
+STREAMS = {"window": 1, "warmup": 2, "sample": 3, "popularity": 4,
+           "kind": 5}
+POINT, PROFILE = 0, 1
 
 
-def rng_for(seed: int, stream: str) -> np.random.Generator:
-    return np.random.default_rng([int(seed) & 0xFFFFFFFFFFFFFFFF,
-                                  STREAMS[stream]])
+def rng_for(seed: int, stream: str, of: str | None = None
+            ) -> np.random.Generator:
+    """The generator of a named stream; ``of`` names the stream whose
+    requests it serves (``rng_for(seed, "kind", "window")`` draws the
+    window's request kinds)."""
+    key = [int(seed) & 0xFFFFFFFFFFFFFFFF, STREAMS[stream]]
+    if of is not None:
+        key.append(STREAMS[of])
+    return np.random.default_rng(key)
 
 
 class PairSource:
@@ -43,6 +59,10 @@ class PairSource:
             raise ValueError(f"unknown pairs {mix['pairs']!r}")
         if mix["levels"] != "uniform":
             raise ValueError(f"unknown levels {mix['levels']!r}")
+        self.profile_share = float(mix.get("profile_share", 0.0))
+        if not 0.0 <= self.profile_share <= 1.0:
+            raise ValueError(f"profile_share {self.profile_share} is not "
+                             "in [0, 1]")
 
     def _vertices(self, rng, n: int) -> np.ndarray:
         if self.mix["pairs"] == "uniform":
@@ -55,6 +75,18 @@ class PairSource:
         t = self._vertices(rng, n).astype(np.int32)
         w = rng.integers(0, self.W, n).astype(np.int32)
         return s, t, w
+
+    def kinds(self, rng, n: int) -> np.ndarray | None:
+        """The kinds (POINT or PROFILE) of n requests drawn from the kind
+        stream ``rng``; None, with nothing drawn, for a mix without
+        profiles."""
+        if self.profile_share <= 0.0:
+            return None
+        if rng is None:
+            raise ValueError("a mix with profiles needs a kind stream")
+        kind = np.full(n, POINT, np.int8)
+        kind[rng.permutation(n)[:round(self.profile_share * n)]] = PROFILE
+        return kind
 
 
 def open_schedule(mix: dict, seconds: float, rng) -> np.ndarray:

@@ -53,12 +53,13 @@ def main(argv=None) -> int:
     counter = drive.CompileCounter()
     warm_up(srv, src, args.seed, cell.mix, counter, rows)
     rng = rng_for(args.seed, "window")
+    kinds = rng_for(args.seed, "kind", "window")
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(cell.mix, rate_per_s=rate)
         lowered = counter.lowered
         batches = srv.stats.batches
         req, start = drive_mix(srv, mix, src, rng, args.seconds,
-                              drive.HostClock())
+                              drive.HostClock(), kinds=kinds)
         lat = (req.deliver - req.due) * 1e3
         q = len(lat) // 4
         close = start + args.seconds
